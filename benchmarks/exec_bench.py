@@ -203,7 +203,8 @@ def _spawn_worker(p: int, full: bool) -> list[dict]:
     from repro.launch.mesh import fake_device_env
 
     env = fake_device_env(p)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # CPU-only: fake devices; the child must never claim a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     cmd = [sys.executable, os.path.abspath(__file__), "--worker",
            str(p)]
@@ -325,14 +326,14 @@ def main(argv=None) -> int:
                   f"pallas_calls")
             print(f"{key}/hbm_passes,{r['hbm_passes']},payload_sweeps")
             print(f"{key}/exec_s,{r['exec_seconds']:.3f},"
-                  f"interpret_walltime")
+                  f"cpu_interpret_walltime")
             print(f"{key}/max_drift,{r['max_drift']},bits_vs_spmd")
             continue
         print(f"{key}/trace_eqns,{r['trace_eqns']},jaxpr_equations")
-        print(f"{key}/trace_s,{r['trace_seconds']:.3f},seconds")
+        print(f"{key}/trace_s,{r['trace_seconds']:.3f},cpu_seconds")
         if "compile_seconds" in r:
             print(f"{key}/compile_s,{r['compile_seconds']:.3f},"
-                  f"seconds")
+                  f"cpu_seconds")
         print(f"{key}/simulated_us,{r['simulated_seconds'] * 1e6:.2f},"
               f"default_ici_clock")
     if args.json:

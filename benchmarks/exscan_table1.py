@@ -76,6 +76,8 @@ def modeled_us(alg: str, p: int, m: int, itemsize: int = 8) -> float:
 
 def measured(p: int = 8) -> dict:
     env = dict(os.environ)
+    # CPU-only: p fake devices; the child must never claim a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
     env["JAX_ENABLE_X64"] = "1"
     src = os.path.join(os.path.dirname(os.path.dirname(

@@ -47,6 +47,8 @@ print("RESULT" + json.dumps(out))
 
 def run(csv_rows: list):
     env = dict(os.environ)
+    # CPU-only: 8 fake devices; the child must never claim a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")

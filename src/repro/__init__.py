@@ -1,11 +1,3 @@
 """Reproduction of "Communication Round and Computation Efficient
 Exclusive Prefix-Sums Algorithms (for MPI_Exscan)" as a jax/TPU system.
-
-Importing the package applies the jax forward-compat backfills (see
-``repro._jax_compat``) so the current-API sources also run on images
-that pin an older jax.
 """
-
-from repro import _jax_compat
-
-_jax_compat.apply()

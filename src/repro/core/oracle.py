@@ -141,7 +141,7 @@ def skips_two_op(p: int) -> list[int]:
     return skips
 
 
-def _exscan_reference(inputs: Sequence[Any], op: Callable, identity: Any):
+def exscan_reference(inputs: Sequence[Any], op: Callable, identity: Any):
     """Sequential exclusive fold: out[r] = V_0 ⊕ … ⊕ V_{r-1}; out[0]=identity."""
     out = [identity]
     acc = None
@@ -320,7 +320,7 @@ def verify(p: int, algorithm: str = "123") -> ScheduleStats:
     inputs = [(r,) for r in range(p)]
     op = lambda lo, hi: lo + hi
     identity = ()
-    expect = _exscan_reference(inputs, op, identity)
+    expect = exscan_reference(inputs, op, identity)
     got, stats = SIMULATORS[algorithm](inputs, op, identity)
     assert got == expect, (
         f"{algorithm} p={p}: wrong result\n got={got}\n want={expect}"

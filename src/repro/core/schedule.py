@@ -1284,6 +1284,14 @@ def _ppermute_up(tree, axis_name, skip: int, p: int):
     return jax.tree.map(lambda t: lax.ppermute(t, axis_name, perm), tree)
 
 
+def _vary_like(x, ref):
+    """``x`` typed as varying over every mesh axis ``ref`` varies over
+    (a no-op outside ``shard_map``'s vma checking), so a loop carry
+    seeded with a rank-invariant value matches a varying body output."""
+    missing = tuple(jax.typeof(ref).vma - jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
+
+
 def _shift_up(tree, axis_name, skip: int, p: int):
     """One communication round: rank r sends to r+skip (r+skip < p).
 
@@ -1529,8 +1537,8 @@ class SPMDExecutor(Executor):
         # round's prep is dead — its result never leaves the loop —
         # so stats count the IR's p−3+S preps, the result-path ⊕.
         ts = jnp.asarray([st.t for st in steps], dtype=jnp.int32)
-        init = (cur, ident, jnp.zeros((), bool),
-                jnp.zeros((), jnp.int32), R)
+        init = (cur, ident, _vary_like(jnp.zeros((), bool), r),
+                _vary_like(jnp.zeros((), jnp.int32), r), R)
         (_, pend, pvalid, pslot, R), _ = lax.scan(body, init, ts)
         R = store(R, pend, pvalid, pslot)  # drain the last round
         return jax.tree.map(_jnp_unsplit, R, x)
@@ -1676,8 +1684,7 @@ class PallasExecutor(SPMDExecutor):
     :meth:`Schedule.kernel_passes` by construction.
 
     Note: ``shard_map`` has no replication rule for ``pallas_call`` —
-    wrap the call site with ``check_vma=False`` (``check_rep=False`` on
-    older jax)."""
+    wrap the call site with ``check_vma=False``."""
 
     def __init__(self, axis_name=None, *, interpret: bool | None = None,
                  block_rows: int = 256, fused: bool = True):

@@ -7,10 +7,11 @@ same-expert entries) plus per-expert totals — the quantities whose
 to build all-to-all dispatch offsets (models/moe.py).
 
 TPU adaptation: a histogram-scan.  Sequential grid over token blocks,
-running per-expert counters in VMEM scratch; within a block the one-hot
-expansion (block_tokens*K, E) is scanned with a vectorized cumsum on the
-VPU.  One pass, no atomics (the GPU idiom) needed — grid order gives
-determinism for free.
+running per-expert counters in VMEM scratch; within a block the
+per-token expert counts (block_tokens, E) are scanned over tokens with
+the engine's sublane scan on the VPU, and slots within a token are
+ordered by a K-step running sum.  One pass, no atomics (the GPU idiom)
+needed — grid order gives determinism for free.
 """
 
 from __future__ import annotations
@@ -22,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.scan_engine import sublane_scan, tuple_combine
+
 
 def _routing_kernel(assign_ref, pos_ref, counts_ref, carry_ref, *, num_experts):
     i = pl.program_id(0)
@@ -32,15 +35,28 @@ def _routing_kernel(assign_ref, pos_ref, counts_ref, carry_ref, *, num_experts):
 
     assign = assign_ref[...]  # (bt, K) int32
     bt, k = assign.shape
-    flat = assign.reshape(bt * k)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bt * k, num_experts), 1)
-    onehot = (flat[:, None] == iota).astype(jnp.int32)  # (bt*K, E)
-    incl = jnp.cumsum(onehot, axis=0)
-    excl = incl - onehot
-    carry = carry_ref[...]  # (1, E)
-    pos_flat = jnp.sum((excl + carry) * onehot, axis=1)  # gather own column
-    pos_ref[...] = pos_flat.reshape(bt, k)
-    new_counts = carry + incl[-1:, :]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bt, k), 1)
+    expert = jax.lax.broadcasted_iota(jnp.int32, (bt, num_experts), 1)
+    # one (bt, E) one-hot per slot; slot j's column is picked with a
+    # lane select + reduction (no 1-D flatten, no lane slice)
+    onehots = []
+    for j in range(k):
+        col = jnp.sum(jnp.where(slot == j, assign, 0), axis=1,
+                      keepdims=True)  # (bt, 1)
+        onehots.append((col == expert).astype(jnp.int32))
+    per_token = functools.reduce(jnp.add, onehots)  # (bt, E)
+    # exclusive count of earlier tokens' entries, per expert
+    incl, = sublane_scan(tuple_combine(jnp.add), (per_token,))
+    base = carry_ref[...] + incl - per_token  # (bt, E)
+    pos = jnp.zeros((bt, k), jnp.int32)
+    for j, oh in enumerate(onehots):
+        # earlier slots of the same token come first (row-major order)
+        pos_j = jnp.sum(base * oh, axis=1, keepdims=True)  # (bt, 1)
+        pos = jnp.where(slot == j, pos_j, pos)
+        base = base + oh
+    pos_ref[...] = pos
+    new_counts = carry_ref[...] + jnp.sum(per_token, axis=0,
+                                          keepdims=True)
     carry_ref[...] = new_counts
 
     @pl.when(i == pl.num_programs(0) - 1)

@@ -1,9 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handle arbitrary shapes/dtypes by lane-padding to TPU-friendly tiles,
-choose block sizes from a VMEM budget, and fall back to the pure-jnp
-reference on CPU (`interpret=True` is used automatically when no TPU is
-present so the kernels still execute — and are tested — everywhere).
+Handle arbitrary shapes/dtypes by lane-padding to TPU-friendly tiles
+and choose block sizes from a VMEM budget.  ``interpret=None`` runs the
+kernels compiled on TPU and in the Pallas interpreter elsewhere, so they
+execute — and are tested — everywhere; pass ``interpret=False`` where a
+fall-back to the interpreter must be impossible (``chip_smoke.py``).
 """
 
 from __future__ import annotations

@@ -87,7 +87,7 @@ def supports(m: monoid_lib.Monoid) -> bool:
 
 
 @functools.lru_cache(maxsize=None)
-def _tuple_combine(op):
+def tuple_combine(op):
     """Lift an elementwise ``op`` to the engine's tuple-of-leaves
     combine signature (cached so jit sees one stable callable per op)."""
 
@@ -106,13 +106,33 @@ _affine_combine = monoid_lib.affine_combine
 # ---------------------------------------------------------------------------
 
 
+def sublane_scan(combine, xs):
+    """Inclusive scan over axis 0 of same-shape (rows, D) leaf tuples.
+
+    Hillis–Steele in log2(rows) rounds: each round rolls the leaves
+    down by a power of two (``pltpu.roll`` along sublanes) and combines
+    every row with the one that far above it, a ``broadcasted_iota``
+    mask keeping rows with no such partner.  Mosaic lowers roll, iota
+    and select; it refuses ``lax.associative_scan``'s strided slices.
+    """
+    rows = xs[0].shape[0]
+    row = lax.broadcasted_iota(jnp.int32, xs[0].shape, 0)
+    k = 1
+    while k < rows:
+        above = tuple(pltpu.roll(x, k, 0) for x in xs)
+        comb = combine(above, xs)
+        xs = tuple(jnp.where(row >= k, c, x) for c, x in zip(comb, xs))
+        k *= 2
+    return xs
+
+
 def _scan_body(combine, n_in, exclusive, traj, fin, *refs):
     """One grid step of the single-pass chunked scan.
 
     ``refs``: n_in chunk inputs, n_in (1, D) init rows, len(traj)
     trajectory outputs, len(fin) final rows, n_in VMEM carry scratch.
     The carry holds the inclusive prefix of every prior chunk; one
-    ``associative_scan`` + one carry combine serve the whole chunk.
+    :func:`sublane_scan` + one carry combine serve the whole chunk.
     """
     x_refs = refs[:n_in]
     init_refs = refs[n_in:2 * n_in]
@@ -129,17 +149,22 @@ def _scan_body(combine, n_in, exclusive, traj, fin, *refs):
             c[...] = ini[...]
 
     xs = tuple(r[...] for r in x_refs)
-    incl = lax.associative_scan(combine, xs, axis=0)
+    incl = sublane_scan(combine, xs)
     cvals = tuple(c[...] for c in carry_refs)
     full = combine(cvals, incl)  # (1, D) carry broadcasts over chunk
+    # rolled down by one row: rows 1.. are the exclusive prefixes and
+    # row 0 wraps around to the chunk's last (inclusive) row — both
+    # without an unaligned sublane slice
+    rolled = tuple(pltpu.roll(f, 1, 0) for f in full)
+    last = tuple(r[0:1, :] for r in rolled)
     if exclusive:
-        outs = tuple(jnp.concatenate([c, f[:-1]], axis=0)
-                     for c, f in zip(cvals, full))
+        row = lax.broadcasted_iota(jnp.int32, rolled[0].shape, 0)
+        outs = tuple(jnp.where(row == 0, c, r)
+                     for c, r in zip(cvals, rolled))
     else:
         outs = full
     for o_ref, j in zip(out_refs, traj):
         o_ref[...] = outs[j]
-    last = tuple(f[-1:, :] for f in full)
     for c, l in zip(carry_refs, last):
         c[...] = l
 
@@ -202,7 +227,7 @@ def monoid_exscan(x, monoid: str = "add", *, block_rows: int = 256,
         raise ValueError(f"rows {n} not a multiple of {block_rows}")
     init = jnp.full((1, d), leaf_identity(m.name, x.dtype), x.dtype)
     (out,), _ = chunked_scan(
-        (x,), (init,), _tuple_combine(m.leaf_op), exclusive=True,
+        (x,), (init,), tuple_combine(m.leaf_op), exclusive=True,
         traj=(0,), final=(), chunk=block_rows, interpret=interpret)
     return out
 

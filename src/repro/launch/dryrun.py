@@ -39,14 +39,6 @@ from repro.launch import steps as steps_lib  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 
 
-def _cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis(), normalized: older jax returns [dict]."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _verify_scan_plans(cfg, mesh) -> list:
     """Resolve the cell's scan spec per mesh axis and execute each
     plan's schedule IR in the numpy simulator executor against the host
@@ -127,7 +119,7 @@ def _probe(cfg, shape, mesh, repeats: int):
                                 unroll_stack=True)
     with scan_api.use_cost_model(mesh_lib.axis_cost_model):
         compiled = steps_lib.lower_cell(cfg_p, shape, mesh).compile()
-    cost = _cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = rl.parse_collectives(compiled.as_text())
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)), coll)
@@ -200,7 +192,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         p2 = _probe(cfg, shape, mesh, 2)
         flops, bytes_hbm, coll = _extrapolate(p1, p2, cfg.n_repeats)
     else:
-        cost = _cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         flops = float(cost.get("flops", 0.0))
         bytes_hbm = float(cost.get("bytes accessed", 0.0))
         coll = rl.parse_collectives(compiled.as_text())

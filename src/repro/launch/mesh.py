@@ -11,6 +11,8 @@ so importing this module does not touch jax device state.
 
 from __future__ import annotations
 
+import os
+
 import jax
 
 from repro.core.scan_api import CostModel, CostProfile
@@ -138,6 +140,23 @@ def fake_device_env(n_devices: int, env=None) -> dict:
     return out
 
 
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to the fixed path
+    ``<repo>/.jax_cache``: the path is part of the cache key, so it
+    must not move between runs.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    path = os.path.join(repo, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -145,8 +164,14 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests, examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small mesh over whatever devices exist (drivers, tests,
+    examples).  Axes are ``Auto``: the model shards through GSPMD
+    propagation and ``constrain`` hints, so parameters placed with
+    :meth:`Model.param_shardings` carry no sharding in their types."""
+    from jax.sharding import AxisType
+
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

@@ -20,9 +20,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.core.scan_api import ScanSpec
 from repro.launch import mesh as mesh_lib
 from repro.models.model import Model
 from repro.serve.metrics import percentile
+from repro.sharding import rules as rules_lib
 
 
 def serve(argv=None):
@@ -34,6 +36,9 @@ def serve(argv=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--data-mesh", type=int, default=1)
     ap.add_argument("--model-mesh", type=int, default=1)
+    ap.add_argument("--exscan", default="auto",
+                    choices=["auto", "123", "1doubling", "two_op",
+                             "native", "ring"])
     args = ap.parse_args(argv)
     for name in ("batch", "prompt_len", "gen", "data_mesh", "model_mesh"):
         if getattr(args, name) < 1:
@@ -41,12 +46,16 @@ def serve(argv=None):
                      f"got {getattr(args, name)}")
 
     get = configs.get_smoke if args.smoke else configs.get
-    cfg = get(args.arch)
+    cfg = get(args.arch, scan=ScanSpec(kind="exclusive",
+                                       algorithm=args.exscan))
     if cfg.encoder_only:
         raise SystemExit("encoder-only arch has no decode loop")
     mesh = mesh_lib.make_host_mesh(args.data_mesh, args.model_mesh)
     model = Model(cfg, mesh)
-    params = model.init_params(jax.random.PRNGKey(0))
+    # drawn under jit straight into their shardings: eager init would
+    # place every tensor on device 0 first
+    params = jax.jit(model.init_params, out_shardings=model.param_shardings(
+        rules_lib.rules_for(cfg)))(jax.random.PRNGKey(0))
 
     B, P, G = args.batch, args.prompt_len, args.gen
     rng = np.random.default_rng(0)
@@ -58,9 +67,18 @@ def serve(argv=None):
 
     with jax.set_mesh(mesh):
         cache = model.init_cache(B, P + G)
+        # the first call of each step compiles: run both once untimed
+        t0 = time.time()
+        warm_logits, warm_cache = prefill(params, cache, prompts)
+        warm_tok = jnp.argmax(warm_logits[:, -1], axis=-1).astype(jnp.int32)
+        jax.block_until_ready(decode(params, warm_cache, warm_tok[:, None], P))
+        t_compile = time.time() - t0
+        del warm_logits, warm_cache, warm_tok
+
         t0 = time.time()
         logits, cache = prefill(params, cache, prompts)
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        jax.block_until_ready(next_tok)
         t_prefill = time.time() - t0
 
         generated = [next_tok]
@@ -75,6 +93,7 @@ def serve(argv=None):
         t_decode = sum(step_s)
 
     out = np.stack([np.asarray(t) for t in generated], axis=1)
+    print(f"compile + warm-up (prefill, decode): {t_compile:.1f} s")
     print(f"prefill {P} tokens x {B} reqs: {t_prefill*1e3:.1f} ms")
     if G == 1:
         # the prompt's last-token argmax IS the only generated token —
@@ -92,4 +111,5 @@ def serve(argv=None):
 
 
 if __name__ == "__main__":
+    mesh_lib.use_compile_cache()
     serve()

@@ -198,4 +198,5 @@ def train(argv=None):
 
 
 if __name__ == "__main__":
+    mesh_lib.use_compile_cache()
     train()

@@ -27,12 +27,10 @@ expert-fraction term comes straight from them — no second top-k pass
 over the full logits outside the manual region.
 
 The per-slot position *within* a device is the Pallas moe_routing kernel
-on TPU and its pure-jnp oracle elsewhere (kernels/ops.py dispatches).
+on TPU and its pure-jnp oracle elsewhere (:func:`local_routing`).
 """
 
 from __future__ import annotations
-
-
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +38,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import scan_api
+from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.models import params as PD
 from repro.models.common import rmsnorm, swiglu
@@ -47,6 +46,15 @@ from repro.models.common import rmsnorm, swiglu
 
 def batch_axes(mesh) -> tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+
+
+def local_routing(top_e, num_experts):
+    """Each (token, slot)'s position within its expert's buffer plus
+    per-expert counts: the Pallas kernel on TPU, its pure-jnp oracle
+    elsewhere (the kernel's interpreter would dominate a CPU step)."""
+    if jax.default_backend() == "tpu":
+        return kops.moe_routing(top_e, num_experts, interpret=False)
+    return kref.moe_routing_ref(top_e, num_experts)
 
 
 def _swiglu_experts(t, gate, up, down):
@@ -164,7 +172,7 @@ def moe_ffn(cfg, p, x, mesh):
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
         # local positions within each expert (Pallas kernel on TPU)
-        positions, counts = kref.moe_routing_ref(top_e, e_pad)
+        positions, counts = local_routing(top_e, e_pad)
         counts = counts.astype(jnp.int32)  # (e_pad,)
 
         # ---- the paper's collective: global dispatch offsets fused

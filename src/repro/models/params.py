@@ -221,6 +221,8 @@ def logical_axes(cfg: ModelConfig):
 
 
 def init_params(cfg: ModelConfig, key: jax.Array):
+    """Random parameters, each drawn directly in ``cfg.dtype`` (no
+    float32 temporary of the largest stacked tensor)."""
     dtype = jnp.dtype(cfg.dtype)
     defs_list = list(_iter_defs(cfg))
     keys = jax.random.split(key, len(defs_list))
@@ -233,11 +235,11 @@ def init_params(cfg: ModelConfig, key: jax.Array):
         elif d.init == "ones":
             v = jnp.ones(shape, dtype)
         elif d.init == "normal":
-            v = (0.02 * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+            v = 0.02 * jax.random.normal(k, shape, dtype)
         else:  # fan_in
             fan_in = d.shape[-2] if len(d.shape) >= 2 else d.shape[-1]
             scale = 1.0 / math.sqrt(fan_in)
-            v = (scale * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+            v = scale * jax.random.normal(k, shape, dtype)
         if path[-1] == "a_log":
             # mamba: A = -exp(a_log); init a_log = log(1..d_state)
             ds = d.shape[-1]

@@ -46,26 +46,3 @@ def run_with_devices(code: str, n_devices: int = 8, x64: bool = True,
         )
     return proc.stdout
 
-
-def fake_hypothesis():
-    """Stand-ins for ``hypothesis`` when it is not installed.
-
-    ``@given(...)`` becomes a skip marker so property tests are reported
-    as skipped (not errors) in minimal containers; everything else in
-    the module still runs.
-    """
-    import pytest
-
-    def given(*args, **kwargs):
-        del args, kwargs
-        return pytest.mark.skip(reason="hypothesis not installed")
-
-    def settings(*args, **kwargs):
-        del args, kwargs
-        return lambda f: f
-
-    class _Strategies:
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    return given, settings, _Strategies()

@@ -15,13 +15,8 @@ p; and the plan cache reports hits for repeated ``plan()`` calls.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as hst
-except ImportError:  # minimal container: property tests skip
-    from helpers import fake_hypothesis
-
-    given, settings, hst = fake_hypothesis()
+from hypothesis import given, settings
+from hypothesis import strategies as hst
 
 from helpers import run_with_devices
 

@@ -12,13 +12,8 @@ import math
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # minimal container: property tests skip
-    from helpers import fake_hypothesis
-
-    given, settings, st = fake_hypothesis()
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import oracle
 

@@ -9,13 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # minimal container: property tests skip
-    from helpers import fake_hypothesis
-
-    given, settings, st = fake_hypothesis()
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 

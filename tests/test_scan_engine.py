@@ -26,13 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # minimal container: property tests skip
-    from helpers import fake_hypothesis
-
-    given, settings, st = fake_hypothesis()
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from helpers import run_with_devices
 

@@ -1,0 +1,117 @@
+"""Ahead-of-time compiles for a described TPU v5e (no chip attached).
+
+The TPU compiler is installed with JAX, and it compiles for a topology
+that is described rather than attached.  These cases compile the
+kernels of the main path at deployment widths, and the p=4 scan
+programs on a 2x2 mesh, so a kernel that Mosaic refuses (an unaligned
+slice, a primitive with no TPU lowering) fails here instead of on the
+chip.  Nothing runs; results are checked by the CPU suites and by
+``chip_smoke.py`` on the chip.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, so a worker that was not given this
+file must not touch it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import shard_map
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.core import monoid as monoid_lib
+from repro.core import schedule as schedule_lib
+from repro.core.scan_api import ScanSpec, scan
+from repro.kernels import ops, scan_engine
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices[:4]), ("x",))
+
+
+def _hlo(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kernel_cases():
+    i32, f32 = jnp.int32, jnp.float32
+    add = monoid_lib.get("add")
+    return {
+        "exscan_add_int32": (lambda x: ops.exscan(x, interpret=False),
+                             [((4096, 1024), i32)]),
+        "ssm_scan_affine_f32": (
+            lambda a, b: ops.ssm_scan(a, b, interpret=False),
+            [((4096, 1024), f32)] * 2),
+        "ssm_chunk_summary_f32": (
+            lambda a, b: ops.ssm_chunk_summary(a, b, interpret=False),
+            [((4096, 1024), f32)] * 2),
+        "moe_routing_e128": (
+            lambda a: ops.moe_routing(a, 128, interpret=False),
+            [((4096, 8), i32)]),
+        "block_combine_int32": (
+            lambda a, b: scan_engine.block_combine(a, b, jnp.add),
+            [((48,), i32)] * 2),
+        "tree_combine_masked_f32": (
+            lambda a, b, k: scan_engine.tree_combine(add, a, b, keep=k),
+            [((1 << 18,), f32)] * 2 + [((), jnp.bool_)]),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_kernel_cases()))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, shapes = _kernel_cases()[case]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    assert "tpu_custom_call" in _hlo(fn, *args)
+
+
+@pytest.mark.parametrize("executor,alg,kernel", [
+    ("pallas", "123", True),
+    ("spmd", "ring", False),
+])
+def test_p4_scan_compiles_for_v5e_2x2(mesh4, executor, alg, kernel):
+    """scan() at p=4, 1 MiB int32 per rank (the ring runs S>1
+    segments through its rolled loop)."""
+    ex = (schedule_lib.PallasExecutor("x", interpret=False)
+          if executor == "pallas" else schedule_lib.SPMDExecutor("x"))
+    spec = ScanSpec(kind="exclusive", monoid="add", algorithm=alg,
+                    axis_name="x")
+    f = shard_map(lambda v: scan(v, spec, executor=ex), mesh=mesh4,
+                  in_specs=P("x"), out_specs=P("x"),
+                  check_vma=executor == "spmd")
+    x = jax.ShapeDtypeStruct((4, 1 << 18), jnp.int32,
+                             sharding=NamedSharding(mesh4, P("x")))
+    hlo = _hlo(f, x)
+    assert ("tpu_custom_call" in hlo) == kernel
+    assert "collective-permute" in hlo
