@@ -1052,7 +1052,16 @@ def _run_plan(pl: ScanPlan, x, m: monoid_lib.Monoid, executor=None):
     # default executor axis only matters for single-axis plans).
     if executor is None:
         executor = schedule_lib.SPMDExecutor(pl.spec.axes[-1])
-    return executor.execute(pl.schedule(), x, m)
+    return _execute(executor, pl.schedule(), x, m)
+
+
+def _execute(executor, sched, x, m: monoid_lib.Monoid):
+    """Run ``sched`` under the scope ``exscan.<schedule>``, the name of
+    the call in the op metadata a profile reads."""
+    import jax
+
+    with jax.named_scope(f"exscan.{sched.algorithm}"):
+        return executor.execute(sched, x, m)
 
 
 def scan(x, spec: ScanSpec, *, cost_model=None, executor=None):
@@ -1173,7 +1182,7 @@ class FusedPlan:
         if executor is None:
             executor = schedule_lib.SPMDExecutor(
                 self.packed.spec.axes[-1])
-        return list(executor.execute(self.schedule(layout), xs, m))
+        return list(_execute(executor, self.schedule(layout), xs, m))
 
     def verify(self, *, rank_elems: int = 3, seed: int = 0) -> dict:
         """Simulator drift check: the fused execution must reproduce k

@@ -1160,6 +1160,14 @@ def _np_unsplit(seg, like):
 _STATEFUL = ("seg_shift", "scan_reduce", "block_exchange")
 
 
+def _step_scope(st: RoundStep, index: int):
+    """Trace-time name of one executed step: ``round<index>.<kind>``
+    for a communication round (``index`` counts the schedule's rounds
+    from 0), the bare kind for local steps (fold, all-gather)."""
+    return jax.named_scope(f"round{index}.{st.kind}" if st.is_round
+                           else st.kind)
+
+
 def _stage_runs(steps):
     runs: list = []
     cur: list = []
@@ -1338,6 +1346,7 @@ class SPMDExecutor(Executor):
     def _execute(self, sched: Schedule, x, m: monoid_lib.Monoid):
         regs: dict = {}
         w = x if sched.init == "x" else m.identity_like(x)
+        r0 = 0  # rounds before this run: the next round's index
         for run in _stage_runs(sched.steps):
             if isinstance(run, RoundStep):  # control step
                 st = run
@@ -1365,80 +1374,85 @@ class SPMDExecutor(Executor):
                 w = self._run_segmented(run, x, m, axis, p,
                                         _run_seg_count(run, sched))
             elif run[0].kind == "scan_reduce":
-                w, prefix = self._run_scan_reduce(run, x, w, m, axis, p)
+                w, prefix = self._run_scan_reduce(run, x, w, m, axis, p,
+                                                  r0)
                 if run[-1].reg:
                     regs[run[-1].reg] = prefix
             elif run[0].kind == "block_exchange":
                 w = self._run_block(run, x, m, axis, p)
             else:
-                w = self._run_steps(run, x, w, m, axis, p)
+                w = self._run_steps(run, x, w, m, axis, p, r0)
+            r0 += sum(st.is_round for st in run)
         outs = tuple(w if o == "$w" else regs[o]
                      for o in sched.outputs)
         return outs[0] if len(outs) == 1 else outs
 
-    def _run_steps(self, steps, x, w, m, axis, p):
+    def _run_steps(self, steps, x, w, m, axis, p, r0):
         r = lax.axis_index(axis)
         gathered = None
         for st in steps:
-            if st.kind == "shift":
-                if st.send == "x":
-                    src = x
-                elif st.send == "w":
-                    src = w
-                else:  # "w_op_x": rank 0's W is identity -> sends V
-                    src = self.combine(m, w, x)
-                    _record_op()
-                recv = _shift_up(src, axis, st.skip, p)
-                has = (r >= st.bound) if st.mask == "ge" else \
-                    (r > st.bound)
-                if st.combine == "op":
-                    # fused masked combine: one select on the combine
-                    # output; ppermute zero-fill on maskless ranks is
-                    # discarded by the select, no identity fixup pass
-                    w = self.masked_combine(m, has, recv, w)
-                    _record_op()
-                else:  # "copy"
+            with _step_scope(st, r0):
+                if st.kind == "shift":
+                    if st.send == "x":
+                        src = x
+                    elif st.send == "w":
+                        src = w
+                    else:  # "w_op_x": rank 0's W is identity -> sends V
+                        src = self.combine(m, w, x)
+                        _record_op()
+                    recv = _shift_up(src, axis, st.skip, p)
+                    has = (r >= st.bound) if st.mask == "ge" else \
+                        (r > st.bound)
+                    if st.combine == "op":
+                        # fused masked combine: one select on the combine
+                        # output; ppermute zero-fill on maskless ranks is
+                        # discarded by the select, no identity fixup pass
+                        w = self.masked_combine(m, has, recv, w)
+                        _record_op()
+                    else:  # "copy"
+                        w = jax.tree.map(
+                            lambda c, v: jnp.where(has, c, v), recv, w)
+                elif st.kind == "exchange":
+                    perm = [(i, i ^ st.skip) for i in range(p)]
+                    _record_round(w)
+                    recv = jax.tree.map(
+                        lambda t: lax.ppermute(t, axis, perm), w)
+                    if m.commutative:
+                        # both combine orders agree: compute one (2→1 ⊕)
+                        w = self.combine(m, recv, w)
+                        _record_op()
+                    else:
+                        low_side = (r & st.skip) != 0  # partner is lower
+                        w = self.exchange_combine(m, recv, w, low_side)
+                        _record_op(2)
+                elif st.kind == "allgather":
+                    _record_allgather()
+                    gathered = jax.tree.map(
+                        lambda t: lax.all_gather(t, axis, axis=0), x)
+                elif st.kind == "fold":
+                    ident = m.identity_like(x)
+
+                    def body(i, acc):
+                        vi = jax.tree.map(lambda g: g[i], gathered)
+                        take = i < r
+                        combined = self.combine(m, acc, vi)
+                        return jax.tree.map(
+                            lambda c, a: jnp.where(take, c, a), combined,
+                            acc)
+
+                    _record_op(st.fold_count)  # body executes fold_count×
+                    w = lax.fori_loop(0, st.fold_count, body, ident)
+                elif st.kind == "bcast":
+                    _record_allgather()
                     w = jax.tree.map(
-                        lambda c, v: jnp.where(has, c, v), recv, w)
-            elif st.kind == "exchange":
-                perm = [(i, i ^ st.skip) for i in range(p)]
-                _record_round(w)
-                recv = jax.tree.map(
-                    lambda t: lax.ppermute(t, axis, perm), w)
-                if m.commutative:
-                    # both combine orders agree: compute one (2→1 ⊕)
-                    w = self.combine(m, recv, w)
-                    _record_op()
-                else:
-                    low_side = (r & st.skip) != 0  # partner is lower
-                    w = self.exchange_combine(m, recv, w, low_side)
-                    _record_op(2)
-            elif st.kind == "allgather":
-                _record_allgather()
-                gathered = jax.tree.map(
-                    lambda t: lax.all_gather(t, axis, axis=0), x)
-            elif st.kind == "fold":
-                ident = m.identity_like(x)
-
-                def body(i, acc):
-                    vi = jax.tree.map(lambda g: g[i], gathered)
-                    take = i < r
-                    combined = self.combine(m, acc, vi)
-                    return jax.tree.map(
-                        lambda c, a: jnp.where(take, c, a), combined,
-                        acc)
-
-                _record_op(st.fold_count)  # body executes fold_count×
-                w = lax.fori_loop(0, st.fold_count, body, ident)
-            elif st.kind == "bcast":
-                _record_allgather()
-                w = jax.tree.map(
-                    lambda t: lax.all_gather(t, axis, axis=0)[st.root],
-                    w)
-            self._note_round_kernels(st, m)
+                        lambda t: lax.all_gather(t, axis, axis=0)[st.root],
+                        w)
+                self._note_round_kernels(st, m)
+            r0 += st.is_round
         return w
 
-    def _run_scan_reduce(self, steps, x, w, m, axis, p):
+    @jax.named_scope("scan_reduce")
+    def _run_scan_reduce(self, steps, x, w, m, axis, p, r0):
         """The fused exscan+allreduce butterfly: W carries the window
         total T (entering as V via init="x"), the auxiliary P the
         exclusive prefix; each round exchanges T with r^skip and the
@@ -1448,18 +1462,20 @@ class SPMDExecutor(Executor):
         one (3→2 ⊕ per round)."""
         r = lax.axis_index(axis)
         prefix = m.identity_like(x)  # hoisted: built once per run
-        for st in steps:
-            perm = [(i, i ^ st.skip) for i in range(p)]
-            _record_round(w)
-            recv = jax.tree.map(
-                lambda t: lax.ppermute(t, axis, perm), w)
-            low_side = (r & st.skip) != 0  # partner covers lower ranks
-            w, prefix = self.scan_reduce_combine(m, recv, w, prefix,
-                                                 low_side)
-            _record_op(2 if m.commutative else 3)
-            self._note_round_kernels(st, m)
+        for i, st in enumerate(steps):
+            with _step_scope(st, r0 + i):
+                perm = [(j, j ^ st.skip) for j in range(p)]
+                _record_round(w)
+                recv = jax.tree.map(
+                    lambda t: lax.ppermute(t, axis, perm), w)
+                low_side = (r & st.skip) != 0  # partner covers lower ranks
+                w, prefix = self.scan_reduce_combine(m, recv, w, prefix,
+                                                     low_side)
+                _record_op(2 if m.commutative else 3)
+                self._note_round_kernels(st, m)
         return w, prefix
 
+    @jax.named_scope("seg_shift")
     def _run_segmented(self, steps, x, m, axis, p, S):
         """The pipelined ring: stream S leaf row-blocks through
         neighbour rounds; per-rank segment indices are dynamic
@@ -1543,6 +1559,7 @@ class SPMDExecutor(Executor):
         R = store(R, pend, pvalid, pslot)  # drain the last round
         return jax.tree.map(_jnp_unsplit, R, x)
 
+    @jax.named_scope("block_exchange")
     def _run_block(self, steps, x, m, axis, p):
         """The block-distributed exscan family (see
         :func:`_build_block`).  The payload lives split into R = 2^t

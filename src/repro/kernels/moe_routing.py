@@ -100,5 +100,6 @@ def moe_routing(
         ],
         scratch_shapes=[pltpu.VMEM((1, num_experts), jnp.int32)],
         interpret=interpret,
+        name="moe_routing",
     )(assignment)
     return positions, counts

@@ -174,15 +174,16 @@ def _scan_body(combine, n_in, exclusive, traj, fin, *refs):
             f_ref[...] = last[j]
 
 
-def chunked_scan(xs, init, combine, *, exclusive=False, traj=(0,),
-                 final=(), chunk=256, interpret=False):
+def chunked_scan(xs, init, combine, *, name, exclusive=False,
+                 traj=(0,), final=(), chunk=256, interpret=False):
     """Single-pass chunked scan over axis 0 of (T, D) leaf tuples.
 
     ``combine`` takes/returns tuples of leaves; ``init`` seeds the VMEM
     carry ((1, D) rows — the exclusive prefix of row 0).  ``traj``
     selects which leaves' trajectories are written, ``final`` which
-    leaves' inclusive totals come back as (1, D) rows.  Returns
-    ``(trajectory_leaves, final_leaves)``.
+    leaves' inclusive totals come back as (1, D) rows.  ``name`` is the
+    kernel's name in HLO and profiles (the calling entry point's).
+    Returns ``(trajectory_leaves, final_leaves)``.
     """
     xs = tuple(xs)
     init = tuple(init)
@@ -208,6 +209,7 @@ def chunked_scan(xs, init, combine, *, exclusive=False, traj=(0,),
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((1, D), x.dtype) for x in xs],
         interpret=interpret,
+        name=name,
     )(*xs, *init)
     return tuple(outs[:len(traj)]), tuple(outs[len(traj):])
 
@@ -227,7 +229,8 @@ def monoid_exscan(x, monoid: str = "add", *, block_rows: int = 256,
         raise ValueError(f"rows {n} not a multiple of {block_rows}")
     init = jnp.full((1, d), leaf_identity(m.name, x.dtype), x.dtype)
     (out,), _ = chunked_scan(
-        (x,), (init,), tuple_combine(m.leaf_op), exclusive=True,
+        (x,), (init,), tuple_combine(m.leaf_op), name="monoid_exscan",
+        exclusive=True,
         traj=(0,), final=(), chunk=block_rows, interpret=interpret)
     return out
 
@@ -243,8 +246,9 @@ def affine_chunk_scan(a, b, h0, *, chunk: int = 256,
     computed it.  Returns (h (T, D), h_final (1, D))."""
     init = (jnp.ones_like(h0), h0)
     (h,), (h_final,) = chunked_scan(
-        (a, b), init, _affine_combine, exclusive=False, traj=(1,),
-        final=(1,), chunk=chunk, interpret=interpret)
+        (a, b), init, _affine_combine, name="affine_chunk_scan",
+        exclusive=False, traj=(1,), final=(1,), chunk=chunk,
+        interpret=interpret)
     return h, h_final
 
 
@@ -257,8 +261,9 @@ def affine_chunk_summary(a, b, *, chunk: int = 256,
     D = a.shape[1]
     init = (jnp.ones((1, D), a.dtype), jnp.zeros((1, D), a.dtype))
     _, (a_tot, b_tot) = chunked_scan(
-        (a, b), init, _affine_combine, exclusive=False, traj=(),
-        final=(0, 1), chunk=chunk, interpret=interpret)
+        (a, b), init, _affine_combine, name="affine_chunk_summary",
+        exclusive=False, traj=(), final=(0, 1), chunk=chunk,
+        interpret=interpret)
     return a_tot, b_tot
 
 
@@ -353,9 +358,10 @@ def _pad_tile(flat, pad_value, block_rows):
     return tiled, br
 
 
-def _round_call(kernel, ins, pad_values, n_out, *, scalar=None,
+def _round_call(kernel, ins, pad_values, n_out, *, name, scalar=None,
                 block_rows=256, interpret=False):
-    """Launch ONE round kernel over same-size flat operands.
+    """Launch ONE round kernel over same-size flat operands, named
+    ``name`` (the calling entry point) in HLO and profiles.
 
     ``ins`` are 1-D same-dtype buffers (a whole dtype group of payload
     leaves, pre-concatenated); each is identity-padded to the (rows,
@@ -384,6 +390,7 @@ def _round_call(kernel, ins, pad_values, n_out, *, scalar=None,
         out_shape=[jax.ShapeDtypeStruct(tiles[0].shape, tiles[0].dtype)
                    for _ in range(n_out)],
         interpret=interpret,
+        name=name,
     )(*operands)
     return [o.reshape(-1)[:n] for o in outs]
 
@@ -403,12 +410,13 @@ def block_combine(a, b, op, *, keep=None, block_rows: int = 256,
     ins = [a.reshape(-1), b.reshape(-1)]
     if keep is None:
         out, = _round_call(functools.partial(_combine_kernel, op), ins,
-                           (pv, pv), 1, block_rows=block_rows,
-                           interpret=interpret)
+                           (pv, pv), 1, name="block_combine",
+                           block_rows=block_rows, interpret=interpret)
     else:
         out, = _round_call(functools.partial(_masked_combine_kernel, op),
-                           ins, (pv, pv), 1, scalar=keep,
-                           block_rows=block_rows, interpret=interpret)
+                           ins, (pv, pv), 1, name="block_combine",
+                           scalar=keep, block_rows=block_rows,
+                           interpret=interpret)
     return out.reshape(shape)
 
 
@@ -435,7 +443,7 @@ def _dtype_groups(leaves):
     return groups
 
 
-def _batched_elementwise(kernel_fn, m, trees, n_out, *, scalar,
+def _batched_elementwise(kernel_fn, m, trees, n_out, *, name, scalar,
                          block_rows, interpret):
     """Run one elementwise round kernel over every leaf of ``trees``
     (same structure each), batched so all leaves of one dtype share a
@@ -453,8 +461,8 @@ def _batched_elementwise(kernel_fn, m, trees, n_out, *, scalar,
                if len(idxs) > 1 else ft[idxs[0]].reshape(-1)
                for ft in flat_trees]
         outs = _round_call(kernel_fn, ins, (pv,) * len(ins), n_out,
-                           scalar=scalar, block_rows=block_rows,
-                           interpret=interpret)
+                           name=name, scalar=scalar,
+                           block_rows=block_rows, interpret=interpret)
         for k, flat in enumerate(outs):
             off = 0
             for i, sz in zip(idxs, sizes):
@@ -489,7 +497,8 @@ def tree_combine(m, lo, hi, *, keep=None, block_rows=256,
             kern = functools.partial(_combine_kernel, op)
         else:
             kern = functools.partial(_masked_combine_kernel, op)
-        out, = _batched_elementwise(kern, m, (lo, hi), 1, scalar=keep,
+        out, = _batched_elementwise(kern, m, (lo, hi), 1,
+                                    name="tree_combine", scalar=keep,
                                     block_rows=block_rows,
                                     interpret=interpret)
         return out
@@ -500,8 +509,8 @@ def tree_combine(m, lo, hi, *, keep=None, block_rows=256,
         kern = (_affine_combine_kernel if keep is None
                 else _affine_masked_kernel)
         flats = _round_call(kern, _pair_ins(plo, phi), _pair_pads(2), 2,
-                            scalar=keep, block_rows=block_rows,
-                            interpret=interpret)
+                            name="tree_combine", scalar=keep,
+                            block_rows=block_rows, interpret=interpret)
         return _pair_out(hi, flats)
     return None
 
@@ -514,7 +523,7 @@ def tree_exchange(m, recv, w, low_side, *, block_rows=256,
     if m.leaf_op is not None:
         kern = functools.partial(_exchange_kernel, m.leaf_op)
         out, = _batched_elementwise(kern, m, (recv, w), 1,
-                                    scalar=low_side,
+                                    name="tree_exchange", scalar=low_side,
                                     block_rows=block_rows,
                                     interpret=interpret)
         return out
@@ -523,7 +532,8 @@ def tree_exchange(m, recv, w, low_side, *, block_rows=256,
         if pr is None or pw is None:
             return None
         flats = _round_call(_affine_exchange_kernel, _pair_ins(pr, pw),
-                            _pair_pads(2), 2, scalar=low_side,
+                            _pair_pads(2), 2, name="tree_exchange",
+                            scalar=low_side,
                             block_rows=block_rows, interpret=interpret)
         return _pair_out(w, flats)
     return None
@@ -539,6 +549,7 @@ def tree_scan_reduce(m, recv, w, prefix, low_side, *, block_rows=256,
         kern = functools.partial(_scan_reduce_kernel, m.leaf_op,
                                  m.commutative)
         w2, p2 = _batched_elementwise(kern, m, (recv, w, prefix), 2,
+                                      name="tree_scan_reduce",
                                       scalar=low_side,
                                       block_rows=block_rows,
                                       interpret=interpret)
@@ -550,7 +561,8 @@ def tree_scan_reduce(m, recv, w, prefix, low_side, *, block_rows=256,
             return None
         flats = _round_call(_affine_scan_reduce_kernel,
                             _pair_ins(pr, pw, pp), _pair_pads(3), 4,
-                            scalar=low_side, block_rows=block_rows,
+                            name="tree_scan_reduce", scalar=low_side,
+                            block_rows=block_rows,
                             interpret=interpret)
         return _pair_out(w, flats[:2]), _pair_out(prefix, flats[2:])
     return None

@@ -86,6 +86,7 @@ def attention_core(
     return out.reshape(B, Sq, H, hd)
 
 
+@jax.named_scope("attn")
 def attention_block(
     cfg,
     p: dict,
@@ -105,45 +106,51 @@ def attention_block(
     """
     B, S, _ = x.shape
     hd = cfg.head_dim_
-    xn = rmsnorm(x, p["norm1"], cfg.norm_eps)
-    q = constrain(jnp.einsum("bsd,dh->bsh", xn, p["wq"]),
-                  "batch", "seq", "heads").reshape(B, S, cfg.n_heads, hd)
-    k = constrain(jnp.einsum("bsd,dh->bsh", xn, p["wk"]),
-                  "batch", "seq_kv", "kv_heads").reshape(
-        B, S, cfg.n_kv_heads, hd)
-    v = constrain(jnp.einsum("bsd,dh->bsh", xn, p["wv"]),
-                  "batch", "seq_kv", "kv_heads").reshape(
-        B, S, cfg.n_kv_heads, hd)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        xn = rmsnorm(x, p["norm1"], cfg.norm_eps)
+        q = constrain(jnp.einsum("bsd,dh->bsh", xn, p["wq"]),
+                      "batch", "seq", "heads").reshape(
+            B, S, cfg.n_heads, hd)
+        k = constrain(jnp.einsum("bsd,dh->bsh", xn, p["wk"]),
+                      "batch", "seq_kv", "kv_heads").reshape(
+            B, S, cfg.n_kv_heads, hd)
+        v = constrain(jnp.einsum("bsd,dh->bsh", xn, p["wv"]),
+                      "batch", "seq_kv", "kv_heads").reshape(
+            B, S, cfg.n_kv_heads, hd)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
 
     if cache is None:
-        out = attention_core(
-            q, k, v, positions, positions,
-            causal=cfg.causal, window=window,
-            attn_softcap=cfg.attn_softcap, chunk=cfg.attn_chunk,
-        )
+        with jax.named_scope("attn_core"):
+            out = attention_core(
+                q, k, v, positions, positions,
+                causal=cfg.causal, window=window,
+                attn_softcap=cfg.attn_softcap, chunk=cfg.attn_chunk,
+            )
         new_cache = None
     else:
-        dup = cache["k"].shape[2] // cfg.n_kv_heads
-        if dup > 1:
-            k = jnp.repeat(k, dup, axis=2)
-            v = jnp.repeat(v, dup, axis=2)
-        ck = lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), cache_len, axis=1)
-        cv = lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), cache_len, axis=1)
-        new_cache = {"k": ck, "v": cv}
-        S_max = ck.shape[1]
-        pos_k = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32),
-                                 (B, S_max))
-        kv_len = jnp.full((B,), cache_len + S, jnp.int32)
-        out = attention_core(
-            q, ck, cv, positions, pos_k,
-            causal=cfg.causal, window=window,
-            attn_softcap=cfg.attn_softcap, chunk=cfg.attn_chunk,
-            kv_len=kv_len,
-        )
-    y = constrain(jnp.einsum("bsh,hd->bsd", out.reshape(B, S, -1),
-                             p["wo"]), "batch", "seq", "embed_act")
-    return x + y, new_cache
+        with jax.named_scope("kv_cache"):
+            dup = cache["k"].shape[2] // cfg.n_kv_heads
+            if dup > 1:
+                k = jnp.repeat(k, dup, axis=2)
+                v = jnp.repeat(v, dup, axis=2)
+            ck = lax.dynamic_update_slice_in_dim(
+                cache["k"], k.astype(cache["k"].dtype), cache_len, axis=1)
+            cv = lax.dynamic_update_slice_in_dim(
+                cache["v"], v.astype(cache["v"].dtype), cache_len, axis=1)
+            new_cache = {"k": ck, "v": cv}
+        with jax.named_scope("attn_core"):
+            S_max = ck.shape[1]
+            pos_k = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32),
+                                     (B, S_max))
+            kv_len = jnp.full((B,), cache_len + S, jnp.int32)
+            out = attention_core(
+                q, ck, cv, positions, pos_k,
+                causal=cfg.causal, window=window,
+                attn_softcap=cfg.attn_softcap, chunk=cfg.attn_chunk,
+                kv_len=kv_len,
+            )
+    with jax.named_scope("attn_out"):
+        y = constrain(jnp.einsum("bsh,hd->bsd", out.reshape(B, S, -1),
+                                 p["wo"]), "batch", "seq", "embed_act")
+        return x + y, new_cache

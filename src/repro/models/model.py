@@ -51,9 +51,10 @@ class Model:
         cfg = self.cfg
         if spec.use_moe:
             return moe_block(cfg, p, x, self.mesh)
-        xn = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        y = swiglu(xn, p["w_gate"], p["w_up"], p["w_down"])
-        return x + y, jnp.zeros((2,), jnp.float32)
+        with jax.named_scope("ffn"):
+            xn = rmsnorm(x, p["norm2"], cfg.norm_eps)
+            y = swiglu(xn, p["w_gate"], p["w_up"], p["w_down"])
+            return x + y, jnp.zeros((2,), jnp.float32)
 
     def _layer(self, spec, p, x, positions, cache=None, cache_len=None):
         cfg = self.cfg
@@ -80,14 +81,14 @@ class Model:
         vlm patch embeddings (prepended) or audio frame embeddings (the
         whole input).  Frontends are stubs per the assignment."""
         cfg = self.cfg
-        if tokens is not None:
+        if tokens is None:
+            return prefix_embeds  # audio: frame embeddings are the input
+        with jax.named_scope("embed"):
             x = jnp.take(p_top["tok_embed"], tokens, axis=0)
             if cfg.frontend == "vision" and prefix_embeds is not None:
                 x = jnp.concatenate(
                     [prefix_embeds.astype(x.dtype), x], axis=1)
-        else:
-            x = prefix_embeds  # audio: frame embeddings are the input
-        return x
+            return x
 
     def _stack(self, params, x, positions):
         """Scan the layer stack. Returns (x, aux_sum)."""
@@ -108,29 +109,31 @@ class Model:
                       else jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(body, policy=policy)
         carry = (x, jnp.zeros((2,), jnp.float32))
-        if cfg.unroll_stack:
-            for r in range(cfg.n_repeats):
-                layer_params = jax.tree.map(lambda t: t[r],
-                                            params["blocks"])
-                carry, _ = body(carry, layer_params)
-        else:
-            carry, _ = lax.scan(body, carry, params["blocks"])
+        with jax.named_scope("layers"):
+            if cfg.unroll_stack:
+                for r in range(cfg.n_repeats):
+                    layer_params = jax.tree.map(lambda t: t[r],
+                                                params["blocks"])
+                    carry, _ = body(carry, layer_params)
+            else:
+                carry, _ = lax.scan(body, carry, params["blocks"])
         return carry
 
     def logits_fn(self, params, x):
         cfg = self.cfg
-        x = rmsnorm(x, params["top"]["final_norm"], cfg.norm_eps)
-        if cfg.tie_embeddings:
-            w = params["top"]["tok_embed"].T
-        else:
-            w = params["top"]["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x, w).astype(jnp.float32)
-        logits = softcap(logits, cfg.logit_softcap)
-        vp = PD.vocab_padded(cfg)
-        if vp != cfg.vocab:
-            vmask = jnp.arange(vp) < cfg.vocab
-            logits = jnp.where(vmask, logits, -1e30)
-        return logits
+        with jax.named_scope("head"):
+            x = rmsnorm(x, params["top"]["final_norm"], cfg.norm_eps)
+            if cfg.tie_embeddings:
+                w = params["top"]["tok_embed"].T
+            else:
+                w = params["top"]["lm_head"]
+            logits = jnp.einsum("bsd,dv->bsv", x, w).astype(jnp.float32)
+            logits = softcap(logits, cfg.logit_softcap)
+            vp = PD.vocab_padded(cfg)
+            if vp != cfg.vocab:
+                vmask = jnp.arange(vp) < cfg.vocab
+                logits = jnp.where(vmask, logits, -1e30)
+            return logits
 
     def forward(self, params, tokens, prefix_embeds=None, positions=None):
         """Full-sequence forward (train / prefill). Returns (logits, aux)."""
@@ -300,17 +303,19 @@ class Model:
                 new_caches.append(nc)
             return h, tuple(new_caches)
 
-        if cfg.unroll_stack:
-            new_caches = []
-            for r in range(cfg.n_repeats):
-                lp = jax.tree.map(lambda t: t[r], params["blocks"])
-                lc = jax.tree.map(lambda t: t[r], cache)
-                x, nc = body(x, (lp, lc))
-                new_caches.append(nc)
-            new_cache = jax.tree.map(
-                lambda *ts: jnp.stack(ts), *new_caches)
-        else:
-            x, new_cache = lax.scan(body, x, (params["blocks"], cache))
+        with jax.named_scope("layers"):
+            if cfg.unroll_stack:
+                new_caches = []
+                for r in range(cfg.n_repeats):
+                    lp = jax.tree.map(lambda t: t[r], params["blocks"])
+                    lc = jax.tree.map(lambda t: t[r], cache)
+                    x, nc = body(x, (lp, lc))
+                    new_caches.append(nc)
+                new_cache = jax.tree.map(
+                    lambda *ts: jnp.stack(ts), *new_caches)
+            else:
+                x, new_cache = lax.scan(body, x,
+                                        (params["blocks"], cache))
         if last_only:
             x = x[:, -1:]
         return self.logits_fn(params, x), new_cache

@@ -164,73 +164,86 @@ def moe_ffn(cfg, p, x, mesh):
             toks = toks_all
             scan_axes = bt
             n_groups = n_data
-        logits = jnp.einsum("nd,de->ne", toks, router).astype(jnp.float32)
-        emask = jnp.arange(e_pad) < e_real
-        logits = jnp.where(emask, logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = lax.top_k(probs, k)  # (n0, k)
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        with jax.named_scope("router"):
+            logits = jnp.einsum("nd,de->ne", toks,
+                                router).astype(jnp.float32)
+            emask = jnp.arange(e_pad) < e_real
+            logits = jnp.where(emask, logits, -jnp.inf)
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_p, top_e = lax.top_k(probs, k)  # (n0, k)
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
         # local positions within each expert (Pallas kernel on TPU)
-        positions, counts = local_routing(top_e, e_pad)
-        counts = counts.astype(jnp.int32)  # (e_pad,)
+        with jax.named_scope("routing"):
+            positions, counts = local_routing(top_e, e_pad)
+            counts = counts.astype(jnp.int32)  # (e_pad,)
 
         # ---- the paper's collective: global dispatch offsets fused
         # with the capacity allreduce (one scan_total schedule) ----
-        if len(scan_axes) >= 1 and n_groups > 1:
-            offsets, totals = scan_api.scan_with_total(
-                counts, cfg.scan_spec.over(
-                    scan_axes if len(scan_axes) > 1 else scan_axes[0],
-                    kind="exclusive", monoid="add"))
-        else:
-            offsets = jnp.zeros_like(counts)
-            totals = counts
+        with jax.named_scope("dispatch_scan"):
+            if len(scan_axes) >= 1 and n_groups > 1:
+                offsets, totals = scan_api.scan_with_total(
+                    counts, cfg.scan_spec.over(
+                        scan_axes if len(scan_axes) > 1
+                        else scan_axes[0],
+                        kind="exclusive", monoid="add"))
+            else:
+                offsets = jnp.zeros_like(counts)
+                totals = counts
 
         cap = max(8, int(cfg.capacity_factor * n0 * k / e_pad))
         cap_global = cap * n_groups
-        flat_e = top_e.reshape(-1)  # (n0*k,)
-        flat_pos = positions.reshape(-1)
-        global_pos = offsets[flat_e] + flat_pos
-        keep = (flat_pos < cap) & (global_pos < cap_global)
+        with jax.named_scope("dispatch"):
+            flat_e = top_e.reshape(-1)  # (n0*k,)
+            flat_pos = positions.reshape(-1)
+            global_pos = offsets[flat_e] + flat_pos
+            keep = (flat_pos < cap) & (global_pos < cap_global)
 
-        # scatter into (e_pad * cap, d) send buffer (drop out-of-bounds)
-        slot = jnp.where(keep, flat_e * cap + flat_pos, e_pad * cap)
-        toks_rep = jnp.repeat(toks, k, axis=0)  # (n0*k, d)
-        buf = jnp.zeros((e_pad * cap, d), xl.dtype)
-        buf = buf.at[slot].set(toks_rep, mode="drop")
+            # scatter into (e_pad * cap, d) send buffer (drop
+            # out-of-bounds)
+            slot = jnp.where(keep, flat_e * cap + flat_pos, e_pad * cap)
+            toks_rep = jnp.repeat(toks, k, axis=0)  # (n0*k, d)
+            buf = jnp.zeros((e_pad * cap, d), xl.dtype)
+            buf = buf.at[slot].set(toks_rep, mode="drop")
 
         # dispatch: (tp, e_local*cap, d) -> all_to_all over "model"
-        buf = buf.reshape(tp, e_local * cap, d)
-        recv = lax.all_to_all(buf, "model", split_axis=0, concat_axis=0,
-                              tiled=False)
-        # recv: (tp_src, e_local, cap, d) -> (e_local, tp_src*cap, d)
-        recv = recv.reshape(tp, e_local, cap, d).transpose(1, 0, 2, 3)
-        recv = recv.reshape(e_local, tp * cap, d)
+        with jax.named_scope("all_to_all"):
+            buf = buf.reshape(tp, e_local * cap, d)
+            recv = lax.all_to_all(buf, "model", split_axis=0,
+                                  concat_axis=0, tiled=False)
+            # recv: (tp_src, e_local, cap, d) -> (e_local, tp_src*cap, d)
+            recv = recv.reshape(tp, e_local, cap, d).transpose(1, 0, 2, 3)
+            recv = recv.reshape(e_local, tp * cap, d)
 
-        if ws:
-            out = _swiglu_experts_ws(recv, gate, up, down, bt_w)
-        else:
-            out = _swiglu_experts(recv, gate, up, down)
+        with jax.named_scope("experts"):
+            if ws:
+                out = _swiglu_experts_ws(recv, gate, up, down, bt_w)
+            else:
+                out = _swiglu_experts(recv, gate, up, down)
 
         # reverse trip
-        out = out.reshape(e_local, tp, cap, d).transpose(1, 0, 2, 3)
-        out = out.reshape(tp, e_local * cap, d)
-        back = lax.all_to_all(out, "model", split_axis=0, concat_axis=0,
-                              tiled=False)
-        back = back.reshape(e_pad * cap, d)
+        with jax.named_scope("all_to_all"):
+            out = out.reshape(e_local, tp, cap, d).transpose(1, 0, 2, 3)
+            out = out.reshape(tp, e_local * cap, d)
+            back = lax.all_to_all(out, "model", split_axis=0,
+                                  concat_axis=0, tiled=False)
+            back = back.reshape(e_pad * cap, d)
 
         # combine: gather own slots, weight by (renormalized) gate probs
-        got = jnp.take(back, jnp.minimum(slot, e_pad * cap - 1), axis=0)
-        valid = (keep & (slot < e_pad * cap))[:, None]
-        got = jnp.where(valid, got, 0)
-        weighted = got.reshape(n0, k, d) * top_p[..., None].astype(xl.dtype)
-        y = weighted.sum(axis=1)  # (n0, d)
-        kept = keep.reshape(n0, k).astype(jnp.float32)
-        if token_split:
-            y = lax.all_gather(y.reshape(1, n0, d), "model", axis=0,
-                               tiled=True)
-            kept = lax.all_gather(kept.reshape(1, n0, k), "model", axis=0,
-                                  tiled=True)
+        with jax.named_scope("combine"):
+            got = jnp.take(back, jnp.minimum(slot, e_pad * cap - 1),
+                           axis=0)
+            valid = (keep & (slot < e_pad * cap))[:, None]
+            got = jnp.where(valid, got, 0)
+            weighted = got.reshape(n0, k, d) * top_p[..., None].astype(
+                xl.dtype)
+            y = weighted.sum(axis=1)  # (n0, d)
+            kept = keep.reshape(n0, k).astype(jnp.float32)
+            if token_split:
+                y = lax.all_gather(y.reshape(1, n0, d), "model", axis=0,
+                                   tiled=True)
+                kept = lax.all_gather(kept.reshape(1, n0, k), "model",
+                                      axis=0, tiled=True)
         # totals: global per-expert dispatch counts (identical on every
         # rank — replicated dispatch computes the same counts, sharded
         # dispatch all-reduced them in the fused scan)
@@ -272,11 +285,13 @@ def moe_ffn(cfg, p, x, mesh):
     return y, aux
 
 
+@jax.named_scope("moe")
 def moe_block(cfg, p, x, mesh):
     """Pre-norm MoE FFN sub-block with optional shared experts."""
     xn = rmsnorm(x, p["norm2"], cfg.norm_eps)
     y, aux = moe_ffn(cfg, p, xn, mesh)
     if cfg.n_shared_experts:
-        y = y + swiglu(xn, p["shared_gate"], p["shared_up"],
-                       p["shared_down"])
+        with jax.named_scope("shared_expert"):
+            y = y + swiglu(xn, p["shared_gate"], p["shared_up"],
+                           p["shared_down"])
     return x + y, aux

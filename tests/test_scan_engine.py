@@ -245,7 +245,7 @@ def test_identity_padding_keeps_pad_lanes_inert():
     out, = scan_engine._round_call(
         __import__("functools").partial(scan_engine._combine_kernel,
                                         jnp.maximum),
-        [a, a], (pv, pv), 1, interpret=True)
+        [a, a], (pv, pv), 1, name="block_combine", interpret=True)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.full(5, -7, np.int32))
 

@@ -14,6 +14,7 @@ file must not touch it.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -88,12 +89,28 @@ def _kernel_cases():
     }
 
 
+# the name each kernel's pallas_call carries: its entry point's
+KERNEL_NAMES = {
+    "exscan_add_int32": "monoid_exscan",
+    "ssm_scan_affine_f32": "affine_chunk_scan",
+    "ssm_chunk_summary_f32": "affine_chunk_summary",
+    "moe_routing_e128": "moe_routing",
+    "block_combine_int32": "block_combine",
+    "tree_combine_masked_f32": "tree_combine",
+}
+
+
 @pytest.mark.parametrize("case", sorted(_kernel_cases()))
 def test_kernel_compiles_for_v5e(one_chip, case):
+    """The kernel compiles to a custom call that takes its entry point's
+    name, as its device op does in a profile (``moe_routing.N``)."""
     fn, shapes = _kernel_cases()[case]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
-    assert "tpu_custom_call" in _hlo(fn, *args)
+    hlo = _hlo(fn, *args)
+    assert "tpu_custom_call" in hlo
+    name = KERNEL_NAMES[case]
+    assert re.search(rf"%{name}(\.\d+)? = .* custom-call\(", hlo), name
 
 
 @pytest.mark.parametrize("executor,alg,kernel", [
