@@ -86,6 +86,46 @@ def attention_core(
     return out.reshape(B, Sq, H, hd)
 
 
+def cached_attention(
+    q: jax.Array,  # (B, 1, H, hd), rope applied
+    ck: jax.Array,  # (B, S_max, KV, hd), valid below cache_len
+    cv: jax.Array,  # (B, S_max, KV, hd)
+    k: jax.Array,  # (B, 1, KV, hd), the step's own key at cache dtype
+    v: jax.Array,  # (B, 1, KV, hd)
+    cache_len: jax.Array,  # scalar: the position of the step's token
+    *,
+    window: int,
+    attn_softcap: float,
+):
+    """One query position against the cache, read in place, and the
+    step's own key and value.
+
+    The keys and masks are those of writing ``k, v`` at ``cache_len``
+    and attending over the written cache (``attention_core`` with
+    ``kv_len = cache_len + 1``): cached positions below ``cache_len``
+    (and inside the sliding window), then the new key.  One softmax
+    runs over both sets; the two value products are summed in float32.
+    The cache is never written, so a caller that holds it stacked over
+    layers reads its slice without copying it."""
+    B, _, H, hd = q.shape
+    S_max, KV = ck.shape[1], ck.shape[2]
+    scale = hd ** -0.5
+    qg = q.reshape(B, 1, KV, H // KV, hd)
+    pk = jnp.arange(S_max, dtype=jnp.int32)
+    mask = pk < cache_len
+    if window:
+        mask &= pk > cache_len - window
+    scores = jnp.concatenate(
+        [_apply_mask(_grouped_scores(qg, ck, scale, attn_softcap), mask),
+         _grouped_scores(qg, k, scale, attn_softcap)], axis=-1)
+    w = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
+    out = (jnp.einsum("bkgcs,bskd->bckgd", w[..., :S_max], cv,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgcs,bskd->bckgd", w[..., S_max:], v,
+                        preferred_element_type=jnp.float32))
+    return out.astype(cv.dtype).reshape(B, 1, H, hd)
+
+
 @jax.named_scope("attn")
 def attention_block(
     cfg,
@@ -97,12 +137,18 @@ def attention_block(
     cache: dict | None = None,
     cache_len: jax.Array | None = None,
 ):
-    """Pre-norm attention sub-block.  Returns (residual_out, new_cache).
+    """Pre-norm attention sub-block.  Returns (residual_out, cache_out).
 
-    Full-sequence mode (cache=None): self-attention over x.
-    Decode mode: x is (B, 1, d); cache holds (k, v) of shape
-    (B, S_max, KVd, hd) with ``cache_len`` valid entries; kv heads are
-    stored duplicated to the TP degree when n_kv < TP (see DESIGN §5).
+    Full-sequence mode (cache=None): self-attention over x; cache_out is
+    None.  With a cache of (k, v), each (B, S_max, KVd, hd) with
+    ``cache_len`` valid entries (kv heads stored duplicated to the TP
+    degree when n_kv < TP, see DESIGN §5):
+
+    * prefill (S > 1) writes the S new rows into the cache and attends
+      over it; cache_out is the written cache;
+    * decode (S == 1) reads the cache without writing it
+      (``cached_attention``); cache_out holds only the new rows, (B, 1,
+      KVd, hd) each, for the caller to write at ``cache_len``.
     """
     B, S, _ = x.shape
     hd = cfg.head_dim_
@@ -134,22 +180,32 @@ def attention_block(
             if dup > 1:
                 k = jnp.repeat(k, dup, axis=2)
                 v = jnp.repeat(v, dup, axis=2)
-            ck = lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), cache_len, axis=1)
-            cv = lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), cache_len, axis=1)
-            new_cache = {"k": ck, "v": cv}
+            k = k.astype(cache["k"].dtype)
+            v = v.astype(cache["v"].dtype)
+            if S == 1:
+                new_cache = {"k": k, "v": v}
+            else:
+                ck = lax.dynamic_update_slice_in_dim(
+                    cache["k"], k, cache_len, axis=1)
+                cv = lax.dynamic_update_slice_in_dim(
+                    cache["v"], v, cache_len, axis=1)
+                new_cache = {"k": ck, "v": cv}
         with jax.named_scope("attn_core"):
-            S_max = ck.shape[1]
-            pos_k = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32),
-                                     (B, S_max))
-            kv_len = jnp.full((B,), cache_len + S, jnp.int32)
-            out = attention_core(
-                q, ck, cv, positions, pos_k,
-                causal=cfg.causal, window=window,
-                attn_softcap=cfg.attn_softcap, chunk=cfg.attn_chunk,
-                kv_len=kv_len,
-            )
+            if S == 1:
+                out = cached_attention(
+                    q, cache["k"], cache["v"], k, v, cache_len,
+                    window=window, attn_softcap=cfg.attn_softcap)
+            else:
+                S_max = ck.shape[1]
+                pos_k = jnp.broadcast_to(
+                    jnp.arange(S_max, dtype=jnp.int32), (B, S_max))
+                kv_len = jnp.full((B,), cache_len + S, jnp.int32)
+                out = attention_core(
+                    q, ck, cv, positions, pos_k,
+                    causal=cfg.causal, window=window,
+                    attn_softcap=cfg.attn_softcap, chunk=cfg.attn_chunk,
+                    kv_len=kv_len,
+                )
     with jax.named_scope("attn_out"):
         y = constrain(jnp.einsum("bsh,hd->bsd", out.reshape(B, S, -1),
                                  p["wo"]), "batch", "seq", "embed_act")

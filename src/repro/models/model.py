@@ -316,6 +316,17 @@ class Model:
             else:
                 x, new_cache = lax.scan(body, x,
                                         (params["blocks"], cache))
+            if S == 1:
+                # decode: attention layers gave only their new rows, (R,
+                # B, 1, KVd, hd); one write per leaf into the stacked
+                # cache, in place where the caller donates it
+                with jax.named_scope("kv_cache"):
+                    new_cache = tuple(
+                        jax.tree.map(
+                            lambda c, rows: lax.dynamic_update_slice_in_dim(
+                                c, rows, cache_len, axis=2), c, nc)
+                        if spec.kind == "attn" else nc
+                        for spec, c, nc in zip(pattern, cache, new_cache))
         if last_only:
             x = x[:, -1:]
         return self.logits_fn(params, x), new_cache

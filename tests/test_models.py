@@ -2,6 +2,8 @@
 
 * forward/loss: finite, correct shapes, for all 10 archs
 * decode-with-cache == full forward (cache correctness), all decodable
+* decode attention over the cache read in place == write-then-attend,
+  and a decode step writes only the new rows
 * train step decreases loss (integration with optimizer)
 * MoE: multi-device (2 data x 4 model) == single-device reference
 """
@@ -81,6 +83,110 @@ def test_decode_matches_forward(name):
     logits_dec = jnp.concatenate(outs, axis=1)
     np.testing.assert_allclose(
         np.asarray(logits_dec), np.asarray(logits_full), atol=2e-3, rtol=1e-2)
+
+
+# (cache_len, kv_dup, window, attn_softcap) over a 16-position cache
+_CACHED_CASES = {
+    "len0": (0, 1, 0, 0.0),
+    "middle": (7, 1, 0, 0.0),
+    "last": (15, 1, 0, 0.0),
+    "kv_dup2": (7, 2, 0, 0.0),
+    "window": (11, 1, 4, 0.0),
+    "window_short_cache": (2, 1, 4, 0.0),
+    "softcap": (7, 1, 0, 30.0),
+    "all": (15, 2, 4, 30.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CACHED_CASES))
+def test_cached_attention_matches_write_then_attend(case):
+    """Decode attention over the cache read in place plus the step's own
+    key and value equals writing them at ``cache_len`` and attending
+    over the written cache, the form prefill keeps."""
+    from jax import lax
+
+    from repro.models.attention import attention_core, cached_attention
+
+    cache_len, dup, window, cap = _CACHED_CASES[case]
+    B, S_max, H, KV, hd = 2, 16, 8, 2, 8
+    rng = np.random.default_rng(cache_len + 10 * dup + window)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    q = normal(B, 1, H, hd)
+    k = jnp.repeat(normal(B, 1, KV, hd), dup, axis=2)
+    v = jnp.repeat(normal(B, 1, KV, hd), dup, axis=2)
+    ck, cv = normal(B, S_max, KV * dup, hd), normal(B, S_max, KV * dup, hd)
+    n = jnp.int32(cache_len)
+    got = jax.jit(lambda *a: cached_attention(
+        *a, window=window, attn_softcap=cap))(q, ck, cv, k, v, n)
+    wk = lax.dynamic_update_slice_in_dim(ck, k, cache_len, axis=1)
+    wv = lax.dynamic_update_slice_in_dim(cv, v, cache_len, axis=1)
+    pos_k = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32),
+                             (B, S_max))
+    want = attention_core(
+        q, wk, wv, jnp.full((B, 1), cache_len, jnp.int32), pos_k,
+        causal=True, window=window, attn_softcap=cap, chunk=16,
+        kv_len=jnp.full((B,), cache_len + 1, jnp.int32))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("name,cache_len,kv_dup,unroll", [
+    ("granite_3_2b", 0, 1, False),
+    ("granite_3_2b", 6, 2, False),
+    ("gemma2_9b", 11, 1, False),
+    ("jamba_1_5_large_398b", 9, 1, False),
+    ("jamba_1_5_large_398b", 4, 1, True),
+    ("qwen2_moe_a2_7b", 15, 1, False),
+])
+def test_decode_writes_only_the_new_rows(name, cache_len, kv_dup, unroll):
+    """One decode step leaves every attention cache row but the one at
+    ``cache_len`` bit-identical, and writes there the key and value a
+    prefill of the same tokens writes; its logits match that prefill's
+    last position.  The rows the cache holds past ``cache_len`` are
+    noise, which the step must not attend to.  ``unroll`` runs the
+    layer stack unrolled, as the dry run compiles it."""
+    over = {"sliding_window": 4} if name == "gemma2_9b" else {}
+    cfg = configs.get_smoke(name, capacity_factor=16.0,
+                            unroll_stack=unroll, **over)
+    mesh = _mesh1()
+    m = Model(cfg, mesh)
+    params = m.init_params(jax.random.PRNGKey(0))
+    B, L = 2, 16
+    rng = np.random.default_rng(cache_len)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, (B, L)), jnp.int32)
+    pattern = cfg.pattern()
+    cache0 = tuple(
+        jax.tree.map(lambda t: jnp.asarray(
+            rng.standard_normal(t.shape), t.dtype), c)
+        if spec.kind == "attn" else c
+        for spec, c in zip(pattern, m.init_cache(B, L, kv_dup)))
+    with jax.set_mesh(mesh):
+        serve = jax.jit(m.serve_step)
+        before = (serve(params, cache0, tokens[:, :cache_len], 0)[1]
+                  if cache_len else cache0)
+        logits, after = jax.jit(m.decode_step)(
+            params, before, tokens[:, cache_len:cache_len + 1], cache_len)
+        ref_logits, ref = serve(params, cache0, tokens[:, :cache_len + 1], 0)
+    keep = np.arange(L) != cache_len
+    n_attn = 0
+    for spec, b, a, r in zip(pattern, before, after, ref):
+        if spec.kind != "attn":
+            continue
+        n_attn += 1
+        for leaf in ("k", "v"):
+            assert a[leaf].shape == b[leaf].shape
+            a_, b_, r_ = (np.asarray(t[leaf]) for t in (a, b, r))
+            np.testing.assert_array_equal(a_[:, :, keep], b_[:, :, keep])
+            np.testing.assert_allclose(a_[:, :, cache_len],
+                                       r_[:, :, cache_len],
+                                       atol=1e-4, rtol=1e-4)
+    assert n_attn
+    np.testing.assert_allclose(np.asarray(logits),
+                               np.asarray(ref_logits[:, -1:]),
+                               atol=2e-3, rtol=1e-2)
 
 
 @pytest.mark.parametrize("name", ["llama3_8b", "qwen2_moe_a2_7b",

@@ -132,3 +132,71 @@ def test_p4_scan_compiles_for_v5e_2x2(mesh4, executor, alg, kernel):
     hlo = _hlo(f, x)
     assert ("tpu_custom_call" in hlo) == kernel
     assert "collective-permute" in hlo
+
+
+def _decode_step_hlo(topo, cfg, n_chips, batch=32, max_len=1536):
+    """The compiled decode step as the serving loop runs it: weights and
+    KV cache sharded by their logical axes over ``(1, n_chips)``, the
+    cache donated.  Returns (hlo, stacked-cache shard dims, the number
+    of parameter leaves)."""
+    from jax.sharding import AxisType
+
+    from repro.models.model import Model
+    from repro.sharding import rules as rules_lib
+
+    mesh = Mesh(np.asarray(topo.devices[:n_chips]).reshape(1, n_chips),
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    model = Model(cfg, mesh)
+    rules = rules_lib.rules_for(cfg)
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree, shardings)
+
+    params = placed(model.abstract_params(), model.param_shardings(rules))
+    shapes = model.abstract_cache(batch, max_len)
+    kv_ok = cfg.n_kv_heads % n_chips == 0
+    cache = placed(shapes, rules_lib.tree_shardings(
+        rules, model.cache_logical_axes(kv_shardable=kv_ok), mesh, shapes))
+    rep = NamedSharding(mesh, P())
+
+    def step(params, cache, tok, pos):
+        logits, cache = model.decode_step(params, cache, tok[:, None], pos)
+        return jnp.argmax(logits[:, -1], -1).astype(jnp.int32), cache
+
+    with jax.set_mesh(mesh):
+        hlo = jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache, jax.ShapeDtypeStruct((batch,), jnp.int32,
+                                                sharding=rep),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+        ).compile().as_text()
+    k = cache[0]["k"]
+    dims = ",".join(map(str, k.sharding.shard_shape(k.shape)))
+    return hlo, dims, len(jax.tree.leaves(params))
+
+
+@pytest.mark.parametrize("arch,n_chips", [
+    ("granite_moe_3b_a800m", 1),
+    ("qwen2_moe_a2_7b", 4),
+])
+def test_decode_step_moves_no_whole_kv_cache(topo, arch, n_chips):
+    """The decode step reads the stacked KV cache in place and writes
+    only the new rows into the donated buffer: no copy and no fresh
+    buffer of the whole cache (the layer scan's stacked outputs), and
+    both cache leaves alias their outputs.  B=32 over 1536 positions,
+    the decode cells' shapes."""
+    from repro import configs
+
+    hlo, dims, n_params = _decode_step_hlo(topo, configs.get(arch),
+                                           n_chips)
+    whole = rf"= bf16\[{dims}\]\{{[^}}]*\}} "
+    copies = re.findall(whole + r"copy\(", hlo)
+    buffers = [ln for ln in re.findall(whole + r"custom-call\(.*", hlo)
+               if "AllocateBuffer" in ln]
+    assert len(copies) + len(buffers) == 0, (copies, buffers)
+    assert re.search(whole + r"dynamic-update-slice\(", hlo)
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
+                      hlo)
+    assert alias, "no input_output_alias"
+    for out, arg in ((1, n_params), (2, n_params + 1)):
+        assert f"{{{out}}}: ({arg}, {{}}" in alias.group(1), alias.group(1)
