@@ -6,14 +6,15 @@ batch into one cache, and runs the first ``warmup_steps`` decode steps.
 The window then runs decode steps back to back: each feeds the token
 the previous step sampled, at positions ``prompt_len`` to
 ``max_len - 1``, and then wraps back to ``prompt_len`` (a new cycle of
-the same prompts).  Each step ends in ``block_until_ready`` of the
-sampled token.
+the same prompts; a recurrent state is put back to what it held after
+the prompt).  Each step ends in ``block_until_ready`` of the sampled
+token.
 
 Correctness: the newest cycle that finished (finishing the current one
-after the window if none did) is run through the float32 reference,
-teacher-forced, with the program's step grouping; the checks are the
-gaps between the reference's best logit and the served token's
-(``modelcell.compare``).
+after the window if none did) is run through the configuration's
+float32 reference, teacher-forced, with the program's step grouping; the
+checks are the gaps between the reference's best logit and the served
+token's (``modelcell.compare``).
 """
 
 from __future__ import annotations
@@ -58,8 +59,10 @@ class Loop:
     """The decode loop's state: position, the current cycle's fed and
     served tokens, and the newest finished cycle."""
 
-    def __init__(self, params, cache, step, first, prompt_len, max_len):
+    def __init__(self, params, cache, step, first, prompt_len, max_len,
+                 rewind=None):
         self.params, self.cache, self.step = params, cache, step
+        self.rewind = rewind
         self.P, self.L = prompt_len, max_len
         self.pos = prompt_len
         self.tok = first
@@ -89,6 +92,8 @@ class Loop:
         if self.pos == self.L:
             self.done = (self.fed, self.outs)
             self.pos, self.fed, self.outs = self.P, [tok], []
+            if self.rewind is not None:
+                self.cache = self.rewind(self.cache)
         else:
             self.fed.append(tok)
 
@@ -100,7 +105,8 @@ def drive(run):
     Bs = tr["prefill_slice"]
     with run.span("setup"):
         model, rules = modelcell.build(conf, run.devices)
-        params = modelcell.init_params(model, rules, seed)
+        params = modelcell.init_params(model, rules, seed,
+                                       conf.get("weights"))
         cache = modelcell.init_cache(model, rules, B, L)
         prompt = prompts(seed, B, P, model.cfg.vocab)
         prefill, step = _fns(model, Bs)
@@ -109,7 +115,11 @@ def drive(run):
             t, cache = prefill(params, cache, jnp.asarray(prompt[b0:b0 + Bs]),
                                b0)
             firsts.append(t)
-        loop = Loop(params, cache, step, jnp.concatenate(firsts), P, L)
+        rewind = modelcell.Rewind.of(cache, modelcell.seq_axes(model))
+        if rewind is not None:
+            cache = rewind(cache)  # compiled here, not in the window
+        loop = Loop(params, cache, step, jnp.concatenate(firsts), P, L,
+                    rewind)
         for _ in range(tr["warmup_steps"]):
             loop.once()
 
@@ -139,14 +149,13 @@ def drive(run):
     # the embedding table is gathered by rows, unless it is also the head
     embed_bytes = 0 if m.get("tie_embeddings") else max(
         s.data.nbytes for s in params["top"]["tok_embed"].addressable_shards)
-    n_model = model.mesh.shape["model"]
-    kv_shards = n_model if m["n_kv_heads"] % n_model == 0 else 1
     run.facts.update(
         chips=len(run.devices), window_s=window_s, steps=len(times),
-        tokens=B * len(times), model_flops=model_flops,
+        tokens=B * len(times), model_flops=model_flops, model=m,
         host_dispatch_s=[t[1] for t in times],
-        step_read_bytes=(param_bytes - embed_bytes) + flops.kv_bytes(
-            m, B, ctx_mean) / kv_shards,
+        step_read_bytes=(param_bytes - embed_bytes) + flops.state_bytes(
+            m, B, ctx_mean) * modelcell.fullest_share(loop.cache,
+                                                      run.devices),
         device_kind=run.devices[0].device_kind)
     metrics = {"decode_tok_s": B * len(times) / window_s,
                "decode_step_p95_ms": float(np.percentile(step_s, 95)) * 1e3}
@@ -164,8 +173,8 @@ def drive(run):
     run.facts["compared"] = [(tokens, group_ids(B, L, P, Bs),
                               np.arange(P, L), served)]
     t0 = time.perf_counter()
-    checks = modelcell.compare(run.facts["compared"], m, shapes, seed, tp,
-                               run.devices[0])
+    checks = modelcell.compare(run.facts["compared"], conf, shapes, seed,
+                               tp, run.devices[0])
     print(f"reference {time.perf_counter() - t0:.1f} s over "
           f"{served.size} served tokens", file=sys.stderr, flush=True)
     return {"metrics": metrics, "checks": checks,

@@ -1,28 +1,27 @@
 """Operations and bytes that each step, kernel and call needs, from the
-configuration's shapes.  Only useful work counts: the k routed experts
-of each token (not capacity padding nor padded experts), attention over
-the context actually held, the output head where logits are produced."""
+configuration's shapes.  Only useful work counts: what each layer kind
+counts is in ``bench/counts/<kind>.py``, summed here over the model's
+layers (its ``LayerSpec`` pattern, repeated), and the output head where
+logits are produced."""
 
 from __future__ import annotations
 
+from bench import spec
 
-def _sizes(m: dict):
-    d, H, KV = m["d_model"], m["n_heads"], m["n_kv_heads"]
-    hd = m.get("head_dim") or d // H
-    f = m.get("d_expert_ff") or m["d_ff"]
-    return d, H, KV, hd, f
+
+def layers(m: dict) -> list:
+    """Every layer's ``LayerSpec``, bottom up: the program's pattern for
+    the configuration, repeated."""
+    from repro.models.config import ModelConfig
+
+    cfg = ModelConfig(**m)
+    return list(cfg.pattern()) * cfg.n_repeats
 
 
 def token_flops(m: dict, ctx: int) -> float:
-    """One token through every layer, attending over ``ctx`` keys; the
-    output head is not included."""
-    d, H, KV, hd, f = _sizes(m)
-    proj = 2 * d * (H * hd + 2 * KV * hd) + 2 * H * hd * d
-    attn = 2 * 2 * H * hd * ctx
-    router = 2 * d * m["n_experts"]
-    experts = m["top_k"] * 3 * 2 * d * f
-    shared = m.get("n_shared_experts", 0) * 3 * 2 * d * f
-    return m["n_layers"] * (proj + attn + router + experts + shared)
+    """One token through every layer, attending over ``ctx`` positions;
+    the output head is not included."""
+    return sum(spec.counts(s.kind).flops(m, s, ctx) for s in layers(m))
 
 
 def head_flops(m: dict) -> float:
@@ -36,17 +35,18 @@ def decode_step_flops(m: dict, batch: int, pos: int) -> float:
 
 def prefill_flops(m: dict, batch: int, seq: int) -> float:
     """A prefill of ``batch`` prompts of ``seq`` tokens, logits of the
-    last position only."""
-    d, H, KV, hd, f = _sizes(m)
-    no_attn = token_flops(m, 0)
-    attn = m["n_layers"] * 2 * 2 * H * hd * seq * (seq + 1) / 2
-    return batch * (seq * no_attn + attn + head_flops(m))
+    last position only: the token at position t attends over t + 1."""
+    per_layer = [(spec.counts(s.kind), s) for s in layers(m)]
+    tokens = sum(c.flops(m, s, t) for c, s in per_layer
+                 for t in range(1, seq + 1))
+    return batch * (tokens + head_flops(m))
 
 
-def kv_bytes(m: dict, batch: int, ctx: int, itemsize: int = 2) -> float:
-    """Key and value bytes of ``batch`` sequences of ``ctx`` positions."""
-    d, H, KV, hd, f = _sizes(m)
-    return m["n_layers"] * batch * ctx * 2 * KV * hd * itemsize
+def state_bytes(m: dict, batch: int, ctx: float) -> float:
+    """Cache or state bytes that a decode step of ``batch`` sequences of
+    ``ctx`` positions reads, over every layer."""
+    return sum(spec.counts(s.kind).state_bytes(m, s, batch, ctx)
+               for s in layers(m))
 
 
 def moe_routing(T: int, K: int, E: int) -> tuple[float, float]:

@@ -17,6 +17,7 @@ all the group's ranks is under ``cap * tp``, where
 
 ``precision="fp8"`` is the control: every matrix product takes its
 operands rounded to float8_e4m3fn under a per-tensor scale.
+``precision="bf16"`` is the witness: the operands rounded to bfloat16.
 
 Weights are drawn from the seed one layer at a time with
 ``bench/weights.py``, in the dtype the configuration serves and the
@@ -34,6 +35,7 @@ import numpy as np
 from jax import lax
 
 from bench import weights as W
+from bench.reference import gap_values, summary  # noqa: F401
 
 HIGHEST = lax.Precision.HIGHEST
 FP8 = jnp.float8_e4m3fn
@@ -46,9 +48,15 @@ def _quant(x):
     return (x * s).astype(FP8).astype(jnp.float32) / s
 
 
+def _bf16(x):
+    return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+
 def mm(spec, a, b, precision):
     if precision == "fp8":
         a, b = _quant(a), _quant(b)
+    elif precision == "bf16":
+        a, b = _bf16(a), _bf16(b)
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
@@ -164,17 +172,18 @@ def _finish_moe(x, y, xs, inv_perm, w, cfg, precision):
     return x + y[inv_perm].reshape(x.shape)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _top_leaf(key, name, shape, dtype):
-    return W.top_leaf(key, name, shape, dtype).astype(jnp.float32)
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _top_leaf(key, name, shape, dtype, draws):
+    return W.top_leaf(key, name, shape, dtype, dict(draws)).astype(
+        jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def _layer_leaves(key, layer, shapes, dtype, n_experts):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _layer_leaves(key, layer, shapes, dtype, n_experts, draws):
     """One layer's weights in float32; the experts cut to the real
     ones (the padded ones are never routed to)."""
     raw = {k: v.astype(jnp.float32) for k, v in W.layer_leaves(
-        key, 0, layer, dict(shapes), dtype).items()}
+        key, 0, layer, dict(shapes), dtype, dict(draws)).items()}
     for name in ("moe_gate", "moe_up", "moe_down"):
         raw[name] = raw[name][:n_experts]
     return raw
@@ -255,11 +264,13 @@ class Reference:
 
     ``model``: the configuration's ``model`` sizes; ``shapes``: the
     served layout, {"top": {name: shape}, "blocks": [{name: per-layer
-    shape}]}; ``tp``: ranks the experts are spread over."""
+    shape}]}; ``tp``: ranks the experts are spread over; ``draws``: the
+    configuration's ``weights`` block."""
 
     def __init__(self, model: dict, shapes: dict, seed: int, tp: int = 1,
-                 precision: str = "f32", device=None):
+                 precision: str = "f32", device=None, draws=None):
         self.m, self.shapes = model, shapes
+        self.draws = W.frozen(draws)
         self.key = W.seed_key(seed)
         self.precision = precision
         self.dtype = jnp.dtype(model.get("dtype", "bfloat16"))
@@ -275,12 +286,13 @@ class Reference:
 
     def _top(self, name):
         return _top_leaf(self._put(self.key), name,
-                         self.shapes["top"][name], self.dtype)
+                         self.shapes["top"][name], self.dtype, self.draws)
 
     def _layer(self, layer):
         shapes = tuple(sorted(self.shapes["blocks"][0].items()))
         return _layer_leaves(self._put(self.key), self._put(
-            jnp.int32(layer)), shapes, self.dtype, self.cfg.n_experts)
+            jnp.int32(layer)), shapes, self.dtype, self.cfg.n_experts,
+            self.draws)
 
     def hidden(self, tokens: np.ndarray, group_id: np.ndarray,
                keep_pos: np.ndarray, chunk: int = 256):
@@ -318,38 +330,3 @@ class Reference:
 
     def logits(self, h, head):
         return mm("bpd,dv->bpv", h, head, self.precision)
-
-
-@jax.jit
-def _gaps(ref_logits, chosen):
-    best = jnp.max(ref_logits, -1)
-    got = jnp.take_along_axis(ref_logits, chosen[..., None], -1)[..., 0]
-    return best - got
-
-
-def gap_values(ref: Reference, h_ref, served: np.ndarray | None = None,
-               ctrl: Reference | None = None, h_ctrl=None,
-               chunk: int = 8) -> np.ndarray:
-    """(B, P): by how much the chosen token's reference logit lies below
-    the reference's best at each compared position.  The chosen token
-    is the served one, or the control's first choice."""
-    head = ref.head()
-    P = h_ref.shape[1]
-    out = []
-    for p0 in range(0, P, chunk):
-        sl = slice(p0, min(P, p0 + chunk))
-        lr = ref.logits(h_ref[:, sl], head)
-        if ctrl is None:
-            chosen = ref._put(jnp.asarray(served[:, sl], jnp.int32))
-        else:
-            chosen = jnp.argmax(ctrl.logits(h_ctrl[:, sl], head), -1)
-        out.append(np.asarray(_gaps(lr, chosen)))
-    return np.concatenate(out, 1)
-
-
-def summary(g: np.ndarray) -> dict:
-    """The widest gap (``max_logit_gap``), the mean gap and the share of
-    positions whose chosen token is not the reference's first."""
-    return {"max_logit_gap": float(g.max()),
-            "mean_logit_gap": float(g.mean()),
-            "mismatch_share": float(np.mean(g > 0))}

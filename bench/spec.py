@@ -1,5 +1,6 @@
 """Finds a cell's pieces by name: BENCHMARK.json, its configuration,
-traffic mix, correctness limits and per-layer metric readers."""
+traffic mix, correctness limits and per-layer metric readers; the
+configuration's reference module and each layer kind's counts."""
 
 from __future__ import annotations
 
@@ -65,6 +66,18 @@ def load_cell(workload: str, benchmark: dict | None = None) -> Cell:
 def driver(kind: str):
     """The driver module for a traffic kind: ``drivers/<kind>.py``."""
     return importlib.import_module(f"bench.drivers.{kind}")
+
+
+def reference(config: dict):
+    """The plain reference a configuration names in its ``reference``
+    key: ``reference/<name>.py`` (interface in ``reference/__init__``)."""
+    return importlib.import_module(f"bench.reference.{config['reference']}")
+
+
+def counts(kind: str):
+    """Operations and state bytes of one layer of a ``LayerSpec`` kind:
+    ``counts/<kind>.py`` (interface in ``counts/__init__``)."""
+    return importlib.import_module(f"bench.counts.{kind}")
 
 
 def metric_reader(name: str):

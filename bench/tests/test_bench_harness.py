@@ -3,74 +3,93 @@
 - the trace reduction on a hand-made trace, and the FLOP and byte
   functions against hand counts;
 - the float32 reference against the program's own forward at small
-  sizes (one device, and four with the experts spread over them);
+  sizes (one device, and four with the experts spread over them), and
+  the seeded weights bit for bit against digests of earlier draws;
 - a rehearsal of every cell's driver at small sizes, with the platform
   and sizes overridden from outside the harness, and the harness's
   refusals (no TPU, a device kind missing from the peaks table);
 - the control (the fp8 reference in the program's place) and planted
-  faults of the timed path, each of which must come out not correct.
+  faults of the timed path, each of which must come out not correct;
+- a configuration of another layer kind joining the benchmark by new
+  files alone (``new_config/``).
+
+The CPU sizes are data: ``sizes/configs/<configuration>.json`` (the
+model sizes of the rehearsal, ``smoke``, and of the control,
+``control``) and ``sizes/traffic/<traffic kind>.json`` (entries that
+replace the traffic mix's).  A cell without them is never rehearsed.
 
 Run: ``python -m pytest bench/tests -q`` from the repository root.
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
+import shutil
 import subprocess
 import sys
 import textwrap
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
+SRC = os.path.join(ROOT, "src")
+sys.path[:0] = [ROOT, SRC]
 
-SMOKE = {
-    "granite-moe-3b-a800m": dict(
-        name="granite-smoke", family="moe", n_layers=2, d_model=48,
-        n_heads=4, n_kv_heads=2, d_ff=64, d_expert_ff=64, vocab=256,
-        n_experts=8, top_k=4, head_dim=12, tie_embeddings=True,
-        dtype="bfloat16"),
-}
-# The size the control is read at: the configuration's own vocabulary
-# and more layers and width than SMOKE, so that its readings come near
-# those at the cell's own size (PERF.md section 2) and are judged
-# against the committed limits.
-CONTROL_SIZE = {
-    "granite-moe-3b-a800m": dict(
-        SMOKE["granite-moe-3b-a800m"], n_layers=8, d_model=128,
-        head_dim=16, vocab=49155),
-}
-TRAFFIC = {
-    "decode": dict(batch=8, prompt_len=8, max_len=56, prefill_slice=4,
-                   warmup_steps=2, trace_steps=3),
-    "prefill": dict(batch=8, prompt_len=32, pool=6, compare_batches=3,
-                    continue_steps=8, trace_batches=2),
-}
+SIZES = os.path.join("bench", "tests", "sizes")
 
 
-def _bench() -> dict:
-    return json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+def _sizes(root=ROOT, part="configs") -> dict:
+    """{name: sizes} of every file in ``sizes/<part>/``."""
+    d = os.path.join(root, SIZES, part)
+    return {f[:-5]: json.load(open(os.path.join(d, f)))
+            for f in sorted(os.listdir(d)) if f.endswith(".json")}
+
+
+# The model sizes of each configuration's rehearsal (``control`` is the
+# size the control is read at: the configuration's own vocabulary and
+# more layers and width, so that its readings come near those at the
+# cell's own size, PERF.md section 2, and are judged against the
+# committed limits), and the traffic entries of each kind.
+SMOKE = {k: v["smoke"] for k, v in _sizes().items()}
+TRAFFIC = _sizes(part="traffic")
+
+
+def _bench(root=ROOT) -> dict:
+    return json.load(open(os.path.join(root, "BENCHMARK.json")))
+
+
+def cpu_sizes(workload, size="smoke", root=ROOT):
+    """(model sizes, traffic entries) a cell is rehearsed at on CPU;
+    fails where its configuration or traffic kind has no sizes file."""
+    bench = _bench(root)
+    w = {w["name"]: w for w in bench["workloads"]}[workload]
+    kind = json.load(open(os.path.join(root, "bench", "traffic",
+                                       w["traffic"] + ".json")))["kind"]
+    configs, traffic = _sizes(root), _sizes(root, "traffic")
+    if w["config"] not in configs or kind not in traffic:
+        pytest.fail(f"{workload}: no CPU sizes in {SIZES} for configuration"
+                    f" {w['config']!r} or traffic kind {kind!r}; a cell "
+                    f"is never rehearsed at full size")
+    return configs[w["config"]][size], traffic[kind]
 
 
 # Runs one cell in a fresh interpreter on CPU devices; ``PATCH`` plants
 # a fault before the run.
 _RUN = """
 import json, os, sys
-sys.path[:0] = [{root!r}, os.path.join({root!r}, "src")]
+sys.path[:0] = [{root!r}, {src!r}]
 os.environ["JAX_COMPILATION_CACHE_DIR"] = {cache!r}
 import bench.run as r
 from bench import spec
 r.PLATFORM = "cpu"
 r.BENCHMARK = {bench!r}
 spec.peaks = lambda kind: {{"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}}
-cell = r.load({workload!r})
-model = {smoke!r}.get(cell.config["name"])
-if model:
-    r.CONFIG_OVERRIDES = {{"model": model}}
-r.TRAFFIC_OVERRIDES = {traffic!r}.get(cell.traffic["kind"], {{}})
+r.CONFIG_OVERRIDES = {{"model": {model!r}}}
+r.TRAFFIC_OVERRIDES = {traffic!r}
 {patch}
 sys.exit(r.main(["--workload", {workload!r}, "--seed", "8589934597",
                  "--seconds", "0.5", "--trace", {trace!r}]))
@@ -92,16 +111,18 @@ def _python(code, n_devices, timeout=600):
                           text=True, timeout=timeout, cwd=ROOT)
 
 
-def _chips(workload):
-    return {w["name"]: w["chips"] for w in _bench()["workloads"]}[workload]
+def _chips(workload, root=ROOT):
+    return {w["name"]: w["chips"]
+            for w in _bench(root)["workloads"]}[workload]
 
 
-def run_cell(tmp_path, workload, trace=0, patch=""):
-    proc = _python(_RUN.format(root=ROOT, cache=str(tmp_path),
-                               workload=workload, smoke=SMOKE,
-                               traffic=TRAFFIC, patch=patch,
-                               trace=str(trace), bench=_bench()),
-                   _chips(workload))
+def run_cell(tmp_path, workload, trace=0, patch="", root=ROOT):
+    model, traffic = cpu_sizes(workload, root=root)
+    proc = _python(_RUN.format(root=root, src=SRC, cache=str(tmp_path),
+                               workload=workload, model=model,
+                               traffic=traffic, patch=patch,
+                               trace=str(trace), bench=_bench(root)),
+                   _chips(workload, root))
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-4000:]
     return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stderr
 
@@ -187,7 +208,7 @@ def test_flops_granite_hand_count():
     assert flops.token_flops(m, 100) == 32 * (proj + experts + router
                                                + attn)
     assert flops.head_flops(m) == 2 * 1536 * 49155
-    assert flops.kv_bytes(m, 2, 10) == 32 * 2 * 10 * 2 * 8 * 64 * 2
+    assert flops.state_bytes(m, 2, 10) == 32 * 2 * 10 * 2 * 8 * 64 * 2
 
 
 def test_flops_prefill_hand_count():
@@ -302,15 +323,72 @@ def test_unknown_device_kind_refused():
 
 
 def test_every_name_resolves_to_its_files():
-    from bench import spec
+    from bench import flops, spec
 
     bench = _bench()
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"], bench)
         assert cell.per_layer, w["name"]
         spec.driver(cell.traffic["kind"])
+        ref = spec.reference(cell.config)
+        assert {"Reference", "gap_values", "summary"} <= set(vars(ref))
+        for layer in flops.layers(cell.config["model"]):
+            counts = spec.counts(layer.kind)
+            assert callable(counts.flops) and callable(counts.state_bytes)
         for m in cell.per_layer:
             assert spec.metric_reader(m["name"])({}) is None
+
+
+@pytest.mark.parametrize("workload", _workloads())
+def test_every_cell_has_cpu_sizes(workload):
+    """A cell's configuration has a sizes file with both model sizes, and
+    its traffic kind one: without them the cell would be rehearsed at
+    full size."""
+    smoke, traffic = cpu_sizes(workload)
+    control, _ = cpu_sizes(workload, "control")
+    assert smoke["n_layers"] <= 8 and control["d_model"] <= 256
+    assert traffic
+
+
+def test_weight_draws_bit_identical():
+    """Every leaf of both MoE configurations, at small shapes, as the
+    program and as the reference draw it, matches the digest of the
+    same draw made before configurations could state their own rules
+    (``weight_digests.json``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from bench import modelcell, weights as W
+
+    pinned = json.load(open(os.path.join(ROOT, "bench", "tests",
+                                         "weight_digests.json")))
+    seed = pinned["seed"]
+
+    def digest(x):
+        return hashlib.sha256(np.asarray(x).tobytes()).hexdigest()[:16]
+
+    for name, want in pinned["configs"].items():
+        config = json.load(open(os.path.join(
+            ROOT, "bench", "configs", name + ".json")))
+        conf = dict(config, model=want["model"],
+                    mesh={"data": 1, "model": 1})
+        model, rules = modelcell.build(conf, jax.devices())
+        params = modelcell.init_params(model, rules, seed,
+                                       conf.get("weights"))
+        got = {f"top/{k}": digest(v) for k, v in params["top"].items()}
+        got.update({f"blocks/{j}/{k}": digest(v)
+                    for j, b in enumerate(params["blocks"])
+                    for k, v in b.items()})
+        assert got == want["leaves"], name
+        shapes = modelcell.served_shapes(model)
+        key, dtype = W.seed_key(seed), jnp.dtype(want["model"]["dtype"])
+        for k, shape in shapes["top"].items():
+            assert digest(W.top_leaf(key, k, shape, dtype, conf.get(
+                "weights"))) == want["leaves"][f"top/{k}"], (name, k)
+        for layer in range(want["model"]["n_layers"]):
+            for k, v in W.layer_leaves(key, 0, layer, shapes["blocks"][0],
+                                       dtype, conf.get("weights")).items():
+                assert digest(v) == digest(params["blocks"][0][k][layer])
 
 
 # ---------------------------------------------------- control and faults
@@ -361,20 +439,31 @@ def test_planted_fault_is_not_correct(tmp_path, workload, fault, patch):
 
 _CAL = """
 import os, sys
-sys.path[:0] = [{root!r}, os.path.join({root!r}, "src")]
+sys.path[:0] = [{root!r}, {src!r}]
 os.environ["JAX_COMPILATION_CACHE_DIR"] = {cache!r}
 import bench.run as r
 from bench import calibrate, spec
 r.PLATFORM = "cpu"
 r.BENCHMARK = {bench!r}
-cell = r.load({workload!r})
-model = {smoke!r}.get(cell.config["name"])
-if model:
-    r.CONFIG_OVERRIDES = {{"model": model}}
-r.TRAFFIC_OVERRIDES = {traffic!r}.get(cell.traffic["kind"], {{}})
-calibrate.main(["--workload", {workload!r}, "--seeds", "1,2,3",
-                "--control-seeds", "1,2,3"])
+r.CONFIG_OVERRIDES = {{"model": {model!r}}}
+r.TRAFFIC_OVERRIDES = {traffic!r}
+calibrate.main(["--workload", {workload!r}, "--seeds", {seeds!r},
+                "--control-seeds", {seeds!r}] + {extra!r})
 """
+
+
+def calibrate_cell(tmp_path, workload, seeds="1,2,3", extra=(),
+                   root=ROOT):
+    """calibrate.py's lines for a cell at its control size on CPU."""
+    model, traffic = cpu_sizes(workload, "control", root)
+    proc = _python(_CAL.format(root=root, src=SRC, cache=str(tmp_path),
+                               workload=workload, model=model,
+                               traffic=traffic, bench=_bench(root),
+                               seeds=seeds, extra=list(extra)),
+                   _chips(workload, root))
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return [json.loads(ln) for ln in proc.stdout.splitlines()
+            if ln.startswith("{")]
 
 
 @pytest.mark.parametrize("workload", _workloads())
@@ -382,13 +471,7 @@ def test_control_is_not_correct(tmp_path, workload):
     """The control, the fp8 reference in the program's place, at a size
     a test run holds: judged against the cell's committed limits as a
     run judges the program, it comes out not correct on every seed."""
-    proc = _python(_CAL.format(root=ROOT, cache=str(tmp_path),
-                               workload=workload, smoke=CONTROL_SIZE,
-                               traffic=TRAFFIC, bench=_bench()),
-                   _chips(workload))
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    lines = [json.loads(ln) for ln in proc.stdout.splitlines()
-             if ln.startswith("{")]
+    lines = calibrate_cell(tmp_path, workload)
     assert len(lines) == 3
     for ln in lines:
         assert ln["control_correct"] is False, ln
@@ -397,8 +480,6 @@ def test_control_is_not_correct(tmp_path, workload):
 def test_benchmark_files_alone_fail(tmp_path):
     """In a directory holding only BENCHMARK.json and bench/, a run
     exits non-zero and prints no result (the program is not there)."""
-    import shutil
-
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
     shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -414,3 +495,79 @@ def test_benchmark_files_alone_fail(tmp_path):
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "repro" in proc.stderr
+
+
+# ------------------------------------------- a configuration by files
+
+
+NEW_CONFIG = os.path.join(ROOT, "bench", "tests", "new_config")
+
+
+def _digests(root) -> dict:
+    """{path under bench/: sha256} of every file but compiled ones."""
+    out = {}
+    for d, dirs, files in os.walk(os.path.join(root, "bench")):
+        dirs[:] = [x for x in dirs if x != "__pycache__"]
+        for f in files:
+            path = os.path.join(d, f)
+            out[os.path.relpath(path, root)] = hashlib.sha256(
+                open(path, "rb").read()).hexdigest()
+    return out
+
+
+def _join(copy):
+    """Adds ``new_config``'s files to the copy, each new, and its list
+    entries to the copy's BENCHMARK.json."""
+    tree = os.path.join(NEW_CONFIG, "bench")
+    for d, _, files in os.walk(tree):
+        for f in files:
+            rel = os.path.relpath(os.path.join(d, f), NEW_CONFIG)
+            dst = os.path.join(copy, rel)
+            assert not os.path.exists(dst), rel
+            os.makedirs(os.path.dirname(dst), exist_ok=True)
+            shutil.copy(os.path.join(d, f), dst)
+    bench = _bench(copy)
+    add = json.load(open(os.path.join(NEW_CONFIG, "entries.json")))
+    bench["configs"] += add["configs"]
+    bench["workloads"] += add["workloads"]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in add["metric_workloads"]:
+            m["workloads"] += add["metric_workloads"][m["name"]]
+    with open(os.path.join(copy, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f, indent=1)
+
+
+@pytest.mark.parametrize("workload,fault,patch", [
+    ("rwkv6-tiny.prefill", "cache_not_written", _UNWRITTEN),
+    ("rwkv6-tiny.decode", "state_unchanged", _STALE)],
+    ids=["rwkv6-tiny.prefill", "rwkv6-tiny.decode"])
+def test_configuration_joins_by_new_files_alone(tmp_path, workload, fault,
+                                                patch):
+    """A configuration of the program's ``rwkv`` layer kind, which the
+    MoE reference cannot compute, joins a copy of the benchmark by new
+    files (configuration with its weight draws, reference, counts, CPU
+    sizes, cells) and list entries of BENCHMARK.json alone: it rehearses
+    correct, traced and untraced; its control and a planted fault come
+    out not correct; no file the copy's bench/ had changes."""
+    copy = tmp_path / "copy"
+    copy.mkdir()
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), copy)
+    shutil.copytree(os.path.join(ROOT, "bench"), copy / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _digests(copy)
+    _join(copy)
+    cache = tmp_path / "cache"
+    for trace in (0, 1):
+        out, err = run_cell(cache, workload, trace, root=str(copy))
+        assert out["correct"] is True, (trace, out, err[-3000:])
+        if trace:
+            assert "mfu." + workload.split(".")[-1] in out["metrics"], out
+    lines = calibrate_cell(cache, workload, seeds="5", extra=["--witness"],
+                           root=str(copy))
+    assert lines[0]["control_correct"] is False, lines
+    assert lines[0]["bf16"]["mean_logit_gap"] < lines[0]["control"][
+        "mean_logit_gap"], lines
+    out, _ = run_cell(cache, workload, patch=patch, root=str(copy))
+    assert out["correct"] is False, (fault, out)
+    after = _digests(copy)
+    assert {k: after[k] for k in before} == before

@@ -1,8 +1,9 @@
-"""Checks of ``bench/scopes.py`` and of the expert-parallel cell on CPU.
+"""Checks of the reduction by program scope (``bench/trace.py``), the
+scope metrics, ``bench/scopes.py`` and the expert-parallel cell on CPU.
 
 - the reduction by program scope on a hand-made trace (nested scopes,
   an op with none, a container op, two devices, the window's edges),
-  with the harness's own reduction of the same trace unchanged;
+  and the scope metrics' helper on the same trace;
 - the tool rehearsed end to end on CPU devices;
 - the float32 reference against the program's forward with the
   qwen2-moe layer (shared expert, untied head) over one and four
@@ -28,7 +29,7 @@ QWEN_CELL = "qwen2-moe.ep4.decode"
 
 
 def test_program_scopes_of_a_path():
-    from bench import scopes
+    from bench import trace as scopes
 
     path = ("jit(step)/jit(main)/layers/while/body/closed_call/moe/"
             "dispatch_scan/exscan.fused_doubling/scan_reduce/"
@@ -78,7 +79,7 @@ def test_op_paths_read_from_event_metadata():
     ``tf_op`` as a string and as a reference to a stat name, an event
     with no ``tf_op`` (an op XLA inserted), and a host plane that is
     not read; fixed-width fields are skipped."""
-    from bench import scopes
+    from bench import trace
 
     def stat_md(i, name):  # stat_metadata map entry
         return _message((1, i), (2, _message((1, i), (2, name))))
@@ -102,22 +103,22 @@ def test_op_paths_read_from_event_metadata():
                      _message((1, 7), (7, 11)))))
     host = _message((1, 1), (2, "/host:CPU"), (5, stat_md(7, "tf_op")))
     space = _message((1, device), (1, host))
-    assert scopes.op_paths(space) == {"/device:TPU:0": {
+    assert trace.op_paths(space) == {"/device:TPU:0": {
         "%fusion.1 = f32[8]": "jit(f)/layers/while/body/attn/kv_cache/add",
         "%copy.5 = f32[8]": "",
         "%fusion.2 = f32[4]": "jit(f)/head/dot_general"}}
 
 
-def test_scope_reduction_hand_counted():
-    from bench import scopes, trace
+L = "jit(step)/layers/while/body/"
 
-    L = "jit(step)/layers/while/body/"
-    # window 0..100 ns.  Device A: embed 0-10, a kv_cache update 10-30
-    # inside attn inside layers, an expert matmul 30-50 inside moe,
-    # an op with no scope 50-60 (argmax), and the head 95-120 (clipped
-    # to 95-100).  Device B: a while loop 0-80 (container: busy time
-    # only) around the same kv_cache op 0-40 and an unscoped copy 40-80.
-    ops = {
+
+def _hand_trace():
+    """Window 0..100 ns.  Device A: embed 0-10, a kv_cache update 10-30
+    inside attn inside layers, an expert matmul 30-50 inside moe, an op
+    with no scope 50-60 (argmax), and the head 95-120 (clipped to
+    95-100).  Device B: a while loop 0-80 (container: busy time only)
+    around the same kv_cache op 0-40 and an unscoped copy 40-80."""
+    return {
         "A": [("fusion.1", 0, 10, "fusion", "jit(step)/embed/gather"),
               ("dus.2", 10, 20, "dynamic-update-slice",
                L + "attn/kv_cache/dynamic_update_slice"),
@@ -131,7 +132,13 @@ def test_scope_reduction_hand_counted():
                L + "attn/kv_cache/dynamic_update_slice"),
               ("copy.7", 40, 40, "copy", "")],
     }
-    r = scopes.reduce_scopes(ops, (0, 100))
+
+
+def test_scope_reduction_hand_counted():
+    from bench import trace
+
+    ops = _hand_trace()
+    r = trace.reduce_events(ops, [], (0, 100))
     s = {k: v * 1e9 for k, v in r["scope_s"].items()}
     # per device mean: layers (20 + 20 | 40) / 2, attn and kv_cache
     # the same, moe and experts 20 / 2, embed 10 / 2, head 5 / 2,
@@ -143,18 +150,51 @@ def test_scope_reduction_hand_counted():
         "embed": 0.5, "layers": 1.5, "attn": 1.0, "kv_cache": 1.0,
         "moe": 0.5, "experts": 0.5, "head": 0.5, "(unscoped)": 1.0})
     # every op once: the top-level scopes and (unscoped) add up to op time
-    top = sum(s[k] for k in scopes.TOP_LEVEL + (scopes.UNSCOPED,))
-    assert top == pytest.approx(r["op_s"] * 1e9) == pytest.approx(72.5)
+    top = sum(s[k] for k in trace.TOP_LEVEL + (trace.UNSCOPED,))
+    op_s = sum(r["op_s"].values()) * 1e9
+    assert top == pytest.approx(op_s) == pytest.approx(72.5)
     assert r["path_s"][("dus.2", "layers/attn/kv_cache")] * 1e9 == \
         pytest.approx(30)
     assert r["path_s"][("copy.7", "")] * 1e9 == pytest.approx(20)
-    # the harness's own reduction of the same trace reads the same op
-    # time and no scope
-    legacy = trace.reduce_events(
+    # ops without their name paths: the same op time, all unscoped
+    bare = trace.reduce_events(
         {k: [op[:4] for op in v] for k, v in ops.items()}, [], (0, 100))
-    assert sum(legacy["op_s"].values()) * 1e9 == pytest.approx(72.5)
-    assert set(legacy) == {"window_s", "busy_s", "idle_share", "op_s",
-                           "category_s", "op_count", "idle_by_span"}
+    assert sum(bare["op_s"].values()) * 1e9 == pytest.approx(72.5)
+    assert bare["scope_s"] == pytest.approx({"(unscoped)": 72.5e-9})
+
+
+def test_scope_metrics_on_the_hand_trace():
+    """The scope readers' helper over the hand-made trace, read as two
+    traced steps of a decode run, with an all-to-all of a scan call
+    added on device A."""
+    from bench import trace
+    from bench.metrics import _scopes
+
+    ops = _hand_trace()
+    ops["A"].append(("all-to-all.6", 60, 10, "all-to-all",
+                     L + "moe/all_to_all/exscan.ring/round0.fold/a"))
+    facts = {"kind": "decode", "traced_steps": 2,
+             "trace": trace.reduce_events(ops, [], (0, 100)),
+             "model": {"name": "t", "family": "moe", "n_layers": 2,
+                       "d_model": 8, "n_heads": 2, "n_kv_heads": 2,
+                       "d_ff": 8, "vocab": 16, "n_experts": 4,
+                       "top_k": 2}}
+    # kv_cache 30 ns a device over 2 steps; moe 10 + 5 (10 / 2 devices)
+    assert _scopes.scope_ms(facts, "decode", "kv_cache") == \
+        pytest.approx(15e-6)
+    assert _scopes.scope_ms(facts, "decode", "moe") == pytest.approx(
+        7.5e-6)
+    assert _scopes.scope_ms(facts, "decode", "attn_core") is None
+    assert _scopes.scope_ms(facts, "prefill", "moe") is None
+    # unscoped 25 of 77.5 ns of op time
+    assert _scopes.unscoped_share(facts, "decode") == pytest.approx(
+        100 * 25 / 77.5)
+    assert _scopes.collective_ms(facts, "decode") == pytest.approx(
+        2.5e-6)
+    # 5 ns under exscan.ring, over 2 steps of 2 MoE layers (2 calls)
+    assert _scopes.exscan_us(facts, "decode") == pytest.approx(
+        5 / 2 / 2 * 1e-3)
+    assert _scopes.unscoped_share({"kind": "decode"}, "decode") is None
 
 
 def test_stall_gaps_are_named_by_open_host_events():

@@ -10,6 +10,12 @@ sharded.  The same function serves the program (all layers of a leaf at
 once, under ``vmap``) and the reference (one layer at a time), so both
 see bit-identical bfloat16 values without the reference taking anything
 the program made.
+
+A configuration may state how some of its leaves are drawn, by leaf
+name, in its ``"weights"`` block (``draws`` here): ``"ones"``,
+``"zeros"`` or ``{"uniform": [lo, hi]}`` (lo + (hi - lo) x a uniform
+value on [0, 1) from the same bits).  A leaf it does not name keeps the
+rule of ``scale``.
 """
 
 from __future__ import annotations
@@ -70,10 +76,37 @@ def scale(name: str, shape) -> float:
     return (2.0 if name == "router" else 1.0) / math.sqrt(fan_in)
 
 
-def leaf_value(key, shape, name: str, dtype):
+def frozen(draws) -> tuple:
+    """A configuration's ``weights`` block as sorted (name, rule) pairs,
+    hashable: each rule ``"ones"``, ``"zeros"`` or ("uniform", lo, hi)."""
+    out = []
+    for name, rule in sorted((draws or {}).items()):
+        if isinstance(rule, dict) and set(rule) == {"uniform"}:
+            lo, hi = rule["uniform"]
+            rule = ("uniform", float(lo), float(hi))
+        elif isinstance(rule, (tuple, list)) and rule[0] == "uniform":
+            rule = ("uniform", float(rule[1]), float(rule[2]))
+        elif rule not in ("ones", "zeros"):
+            raise ValueError(f"weights: no draw rule {rule!r} for {name!r}")
+        out.append((name, rule))
+    return tuple(out)
+
+
+def leaf_value(key, shape, name: str, dtype, draws=None):
     """Uniform on [-sqrt(3), sqrt(3)) times ``scale``; norms are 1 plus
-    that.  Exact up to the one multiply and the final cast."""
+    that.  Exact up to the one multiply and the final cast.  ``draws``
+    ({name: rule}, rules as ``frozen`` gives them) overrides that rule
+    for the leaves it names."""
+    rule = (draws or {}).get(name)
+    if rule == "ones":
+        return jnp.ones(shape, dtype)
+    if rule == "zeros":
+        return jnp.zeros(shape, dtype)
     bits = _bits(key, tuple(shape))
+    if rule is not None:
+        _, lo, hi = rule
+        u = (bits >> 8).astype(jnp.float32) * np.float32(2.0 ** -24)
+        return (np.float32(lo) + np.float32(hi - lo) * u).astype(dtype)
     u = (bits >> 9).astype(jnp.int32) - (1 << 22)  # [-2^22, 2^22)
     v = u.astype(jnp.float32) * np.float32(
         math.sqrt(3.0) * 2.0 ** -22 * scale(name, shape))
@@ -82,14 +115,16 @@ def leaf_value(key, shape, name: str, dtype):
     return v.astype(dtype)
 
 
-def params(abstract, base):
+def params(abstract, base, draws=None):
     """The program's parameter tree (shapes from ``abstract``: the
     model's ``abstract_params()``), drawn from the key ``base``
-    (``seed_key(seed)``).  Call under ``jax.jit`` with the key as an
-    argument, so one compiled program serves every seed, and the
-    model's shardings as ``out_shardings``."""
+    (``seed_key(seed)``) and the configuration's ``weights`` block
+    ``draws``.  Call under ``jax.jit`` with the key as an argument, so
+    one compiled program serves every seed, and the model's shardings
+    as ``out_shardings``."""
+    draws = dict(frozen(draws))
     top = {name: leaf_value(_path_key(base, f"top/{name}"), s.shape, name,
-                            s.dtype)
+                            s.dtype, draws)
            for name, s in abstract["top"].items()}
     blocks = []
     for j, defs in enumerate(abstract["blocks"]):
@@ -99,20 +134,22 @@ def params(abstract, base):
             layers = jnp.arange(s.shape[0])
             out[name] = jax.vmap(
                 lambda l, k=k, s=s, name=name: leaf_value(
-                    _fold(k, l), s.shape[1:], name, s.dtype)
+                    _fold(k, l), s.shape[1:], name, s.dtype, draws)
             )(layers)
         blocks.append(out)
     return {"top": top, "blocks": tuple(blocks)}
 
 
-def top_leaf(base, name: str, shape, dtype):
+def top_leaf(base, name: str, shape, dtype, draws=None):
     """One top-level leaf, as ``params`` draws it."""
-    return leaf_value(_path_key(base, f"top/{name}"), shape, name, dtype)
+    return leaf_value(_path_key(base, f"top/{name}"), shape, name, dtype,
+                      dict(frozen(draws)))
 
 
-def layer_leaves(base, j: int, layer, shapes: dict, dtype):
+def layer_leaves(base, j: int, layer, shapes: dict, dtype, draws=None):
     """Layer ``layer`` of pattern position ``j``: {name: value}, with
     ``shapes`` the per-layer shapes (without the stacking axis)."""
+    draws = dict(frozen(draws))
     return {name: leaf_value(
         _fold(_path_key(base, f"blocks/{j}/{name}"), layer),
-        shape, name, dtype) for name, shape in shapes.items()}
+        shape, name, dtype, draws) for name, shape in shapes.items()}
