@@ -4,14 +4,17 @@
   functions against hand counts;
 - the float32 reference against the program's own forward at small
   sizes (one device, and four with the experts spread over them), and
-  the seeded weights bit for bit against digests of earlier draws;
+  the seeded weights bit for bit against digests of earlier draws, a
+  configuration's own draw rules, and the cache's recurrent-state
+  leaves (zeroed, kept, rewound);
 - a rehearsal of every cell's driver at small sizes, with the platform
   and sizes overridden from outside the harness, and the harness's
   refusals (no TPU, a device kind missing from the peaks table);
 - the control (the fp8 reference in the program's place) and planted
   faults of the timed path, each of which must come out not correct;
-- a configuration of another layer kind joining the benchmark by new
-  files alone (``new_config/``).
+- a four-chip prefill cell of a configuration the benchmark already has
+  joining a copy of it by data files alone (``four_chip_prefill/``:
+  its limits file and list entries), rehearsed on 4 CPU devices.
 
 The CPU sizes are data: ``sizes/configs/<configuration>.json`` (the
 model sizes of the rehearsal, ``smoke``, and of the control,
@@ -391,6 +394,64 @@ def test_weight_draws_bit_identical():
                 assert digest(v) == digest(params["blocks"][0][k][layer])
 
 
+def test_weight_draw_rules():
+    """A configuration's ``weights`` rules (``ones``, ``zeros``,
+    ``uniform``) as the program draws them (all layers under ``vmap``)
+    and as the reference draws them (one layer at a time), bit for bit;
+    a leaf the block does not name keeps the default draw."""
+    import jax
+    import jax.numpy as jnp
+
+    from bench import weights as W
+
+    draws = {"a": "ones", "b": "zeros", "c": {"uniform": [-5, -1]}}
+    shapes = {"a": (16,), "b": (16,), "c": (64, 8), "d": (64, 8)}
+    abstract = {"top": {}, "blocks": ({k: jax.ShapeDtypeStruct(
+        (3,) + s, jnp.bfloat16) for k, s in shapes.items()},)}
+    key = W.seed_key(8589934597)
+    prog = jax.jit(lambda k: W.params(abstract, k, draws))(key)["blocks"][0]
+    for layer in range(3):
+        ref = W.layer_leaves(key, 0, layer, shapes, jnp.bfloat16, draws)
+        for k in shapes:
+            assert np.array_equal(np.asarray(prog[k][layer]),
+                                  np.asarray(ref[k])), (k, layer)
+    c = np.asarray(prog["c"], np.float32)
+    assert np.all(prog["a"] == 1) and np.all(prog["b"] == 0)
+    assert c.min() >= -5 and c.max() <= -1 and abs(c.mean() + 3) < 0.1
+    d = np.asarray(prog["d"], np.float32)
+    assert np.abs(d).max() <= np.sqrt(3) / np.sqrt(64) * 1.01
+    with pytest.raises(ValueError):
+        W.frozen({"a": "twos"})
+
+
+def test_recurrent_state_leaves():
+    """A cache leaf with no sequence axis is a recurrent state: zeroed
+    for new sequences, kept as it is when the sequence axes widen, and
+    put back to a saved moment by ``Rewind``; keys and values are left
+    to the steps that write them."""
+    import jax.numpy as jnp
+
+    from bench import modelcell
+
+    cache = {"kv": jnp.arange(24.0).reshape(2, 4, 3),
+             "state": jnp.full((2, 3), 7.0)}
+    axes = [1, None]  # jax.tree.leaves order: "kv", "state"
+    assert modelcell.Rewind.of(cache, [1, 1]) is None
+    rewind = modelcell.Rewind.of(cache, axes)
+    new = modelcell.fresh(cache, axes)
+    assert np.all(np.asarray(new["state"]) == 0)
+    assert np.array_equal(np.asarray(new["kv"]), np.asarray(cache["kv"]))
+    wide = modelcell.widen(cache, axes, 2)
+    assert wide["kv"].shape == (2, 6, 3) and np.all(
+        np.asarray(wide["kv"][:, 4:]) == 0)
+    assert np.array_equal(np.asarray(wide["state"]),
+                          np.asarray(cache["state"]))
+    back = rewind({"kv": new["kv"] + 1, "state": new["state"] - 1})
+    assert np.all(np.asarray(back["state"]) == 7)
+    assert np.array_equal(np.asarray(back["kv"]),
+                          np.asarray(cache["kv"]) + 1)
+
+
 # ---------------------------------------------------- control and faults
 
 _STALE = """
@@ -497,10 +558,11 @@ def test_benchmark_files_alone_fail(tmp_path):
     assert "repro" in proc.stderr
 
 
-# ------------------------------------------- a configuration by files
+# ------------------------------------------- a cell by data files
 
 
-NEW_CONFIG = os.path.join(ROOT, "bench", "tests", "new_config")
+FOUR_CHIP_PREFILL = os.path.join(ROOT, "bench", "tests",
+                                 "four_chip_prefill")
 
 
 def _digests(root) -> dict:
@@ -515,20 +577,20 @@ def _digests(root) -> dict:
     return out
 
 
-def _join(copy):
-    """Adds ``new_config``'s files to the copy, each new, and its list
-    entries to the copy's BENCHMARK.json."""
-    tree = os.path.join(NEW_CONFIG, "bench")
+def _join(copy, fixture):
+    """Adds the fixture's files under ``bench/`` to the copy, each new,
+    and its list entries (``entries.json``) to the copy's
+    BENCHMARK.json."""
+    tree = os.path.join(fixture, "bench")
     for d, _, files in os.walk(tree):
         for f in files:
-            rel = os.path.relpath(os.path.join(d, f), NEW_CONFIG)
+            rel = os.path.relpath(os.path.join(d, f), fixture)
             dst = os.path.join(copy, rel)
             assert not os.path.exists(dst), rel
             os.makedirs(os.path.dirname(dst), exist_ok=True)
             shutil.copy(os.path.join(d, f), dst)
     bench = _bench(copy)
-    add = json.load(open(os.path.join(NEW_CONFIG, "entries.json")))
-    bench["configs"] += add["configs"]
+    add = json.load(open(os.path.join(fixture, "entries.json")))
     bench["workloads"] += add["workloads"]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if m["name"] in add["metric_workloads"]:
@@ -537,31 +599,37 @@ def _join(copy):
         json.dump(bench, f, indent=1)
 
 
-@pytest.mark.parametrize("workload,fault,patch", [
-    ("rwkv6-tiny.prefill", "cache_not_written", _UNWRITTEN),
-    ("rwkv6-tiny.decode", "state_unchanged", _STALE)],
-    ids=["rwkv6-tiny.prefill", "rwkv6-tiny.decode"])
-def test_configuration_joins_by_new_files_alone(tmp_path, workload, fault,
-                                                patch):
-    """A configuration of the program's ``rwkv`` layer kind, which the
-    MoE reference cannot compute, joins a copy of the benchmark by new
-    files (configuration with its weight draws, reference, counts, CPU
-    sizes, cells) and list entries of BENCHMARK.json alone: it rehearses
-    correct, traced and untraced; its control and a planted fault come
-    out not correct; no file the copy's bench/ had changes."""
+@pytest.mark.parametrize("fault,patch", [
+    ("cache_not_written", _UNWRITTEN), ("token_altered", _ALTERED)],
+    ids=["cache_not_written", "token_altered"])
+def test_four_chip_prefill_cell_joins_by_data_files_alone(tmp_path, fault,
+                                                          patch):
+    """A four-chip prefill cell of a configuration the benchmark has
+    (``four_chip_prefill/``: qwen2-moe-a2.7b under the prefill mix,
+    experts over 4 devices) joins a copy of the benchmark by its limits
+    file and list entries of BENCHMARK.json alone: on 4 CPU devices it
+    rehearses correct, traced and untraced; its fp8 control comes out
+    not correct, with the bfloat16 witness below the control; the
+    planted fault comes out not correct; no file the copy's bench/ had
+    changes."""
     copy = tmp_path / "copy"
     copy.mkdir()
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), copy)
     shutil.copytree(os.path.join(ROOT, "bench"), copy / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     before = _digests(copy)
-    _join(copy)
+    _join(copy, FOUR_CHIP_PREFILL)
+    workload = "qwen2-moe.ep4.prefill"
+    assert _chips(workload, str(copy)) == 4
     cache = tmp_path / "cache"
     for trace in (0, 1):
         out, err = run_cell(cache, workload, trace, root=str(copy))
         assert out["correct"] is True, (trace, out, err[-3000:])
+        assert out["device"]["count"] == 4, out
         if trace:
-            assert "mfu." + workload.split(".")[-1] in out["metrics"], out
+            assert "mfu.prefill" in out["metrics"], out
+        else:
+            assert "prefill_tok_s" in out["metrics"], out
     lines = calibrate_cell(cache, workload, seeds="5", extra=["--witness"],
                            root=str(copy))
     assert lines[0]["control_correct"] is False, lines
