@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.models.common import rmsnorm, rope, softcap
+from repro.models.params import LANE
 from repro.sharding.ctx import constrain
 
 NEG_INF = -1e30
@@ -86,12 +87,22 @@ def attention_core(
     return out.reshape(B, Sq, H, hd)
 
 
+def merged_heads(head_dim: int) -> bool:
+    """Whether an attention cache leaf stores heads x head_dim merged on
+    one axis, ``(B, S_max, KVd * hd)``, rather than ``(B, S_max, KVd,
+    hd)``.  A head narrower than the 128 lanes of a TPU tile would
+    otherwise be padded to them, so the compiler lays such a leaf out
+    sequence-minor, and a one-position write touches a lane of every
+    tile; merged, a written position is whole rows."""
+    return head_dim % LANE != 0
+
+
 def cached_attention(
     q: jax.Array,  # (B, 1, H, hd), rope applied
-    ck: jax.Array,  # (B, S_max, KV, hd), valid below cache_len
-    cv: jax.Array,  # (B, S_max, KV, hd)
-    k: jax.Array,  # (B, 1, KV, hd), the step's own key at cache dtype
-    v: jax.Array,  # (B, 1, KV, hd)
+    ck: jax.Array,  # (B, S_max, KV, hd) or merged (B, S_max, KV * hd)
+    cv: jax.Array,  # as ck
+    k: jax.Array,  # (B, 1, ...) in ck's form: the step's own key at
+    v: jax.Array,  # cache dtype, and its value
     cache_len: jax.Array,  # scalar: the position of the step's token
     *,
     window: int,
@@ -106,24 +117,58 @@ def cached_attention(
     (and inside the sliding window), then the new key.  One softmax
     runs over both sets; the two value products are summed in float32.
     The cache is never written, so a caller that holds it stacked over
-    layers reads its slice without copying it."""
+    layers reads its slice without copying it.
+
+    A merged leaf (``merged_heads``) is read over its full width with
+    block-diagonal products: each query head sits in its KV head's
+    lanes with zeros elsewhere, and keeps its own block of the value
+    product.  No view of the leaf per head is taken, which on a TPU
+    would copy it."""
     B, _, H, hd = q.shape
-    S_max, KV = ck.shape[1], ck.shape[2]
+    S_max = ck.shape[1]
     scale = hd ** -0.5
-    qg = q.reshape(B, 1, KV, H // KV, hd)
+    if ck.ndim == 4:
+        KV = ck.shape[2]
+        qg = q.reshape(B, 1, KV, H // KV, hd)
+
+        def scores_of(c):  # (B, KV, G, 1, S)
+            return _grouped_scores(qg, c, scale, attn_softcap)
+
+        def values_of(w, c):  # (B, 1, KV, G, hd)
+            return jnp.einsum("bkgcs,bskd->bckgd", w, c,
+                              preferred_element_type=jnp.float32)
+
+        def heads(out):
+            return out.reshape(B, 1, H, hd)
+    else:
+        KV = ck.shape[2] // hd
+        own = (jnp.arange(H)[:, None] // (H // KV)
+               == jnp.arange(KV)[None, :])  # (H, KV)
+        qb = (q[:, 0, :, None, :] * own[..., None].astype(q.dtype)
+              ).reshape(B, H, KV * hd)
+
+        def scores_of(c):  # (B, H, S)
+            s = jnp.einsum("bhj,bsj->bhs", qb, c,
+                           preferred_element_type=jnp.float32)
+            return softcap(s * scale, attn_softcap)
+
+        def values_of(w, c):  # (B, H, KV * hd)
+            return jnp.einsum("bhs,bsj->bhj", w, c,
+                              preferred_element_type=jnp.float32)
+
+        def heads(out):
+            out = jnp.sum(out.reshape(B, H, KV, hd) * own[..., None],
+                          axis=2)
+            return out.reshape(B, 1, H, hd)
     pk = jnp.arange(S_max, dtype=jnp.int32)
     mask = pk < cache_len
     if window:
         mask &= pk > cache_len - window
     scores = jnp.concatenate(
-        [_apply_mask(_grouped_scores(qg, ck, scale, attn_softcap), mask),
-         _grouped_scores(qg, k, scale, attn_softcap)], axis=-1)
+        [_apply_mask(scores_of(ck), mask), scores_of(k)], axis=-1)
     w = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
-    out = (jnp.einsum("bkgcs,bskd->bckgd", w[..., :S_max], cv,
-                      preferred_element_type=jnp.float32)
-           + jnp.einsum("bkgcs,bskd->bckgd", w[..., S_max:], v,
-                        preferred_element_type=jnp.float32))
-    return out.astype(cv.dtype).reshape(B, 1, H, hd)
+    out = values_of(w[..., :S_max], cv) + values_of(w[..., S_max:], v)
+    return heads(out).astype(cv.dtype)
 
 
 @jax.named_scope("attn")
@@ -140,15 +185,17 @@ def attention_block(
     """Pre-norm attention sub-block.  Returns (residual_out, cache_out).
 
     Full-sequence mode (cache=None): self-attention over x; cache_out is
-    None.  With a cache of (k, v), each (B, S_max, KVd, hd) with
-    ``cache_len`` valid entries (kv heads stored duplicated to the TP
-    degree when n_kv < TP, see DESIGN §5):
+    None.  With a cache of (k, v), each (B, S_max, KVd, hd), or (B,
+    S_max, KVd * hd) where ``merged_heads(hd)``, with ``cache_len``
+    valid entries (kv heads stored duplicated to the TP degree when
+    n_kv < TP, see DESIGN §5):
 
     * prefill (S > 1) writes the S new rows into the cache and attends
       over it; cache_out is the written cache;
     * decode (S == 1) reads the cache without writing it
       (``cached_attention``); cache_out holds only the new rows, (B, 1,
-      KVd, hd) each, for the caller to write at ``cache_len``.
+      ...) in the cache's form, for the caller to write at
+      ``cache_len``.
     """
     B, S, _ = x.shape
     hd = cfg.head_dim_
@@ -175,13 +222,18 @@ def attention_block(
             )
         new_cache = None
     else:
+        merged = cache["k"].ndim == 3
         with jax.named_scope("kv_cache"):
-            dup = cache["k"].shape[2] // cfg.n_kv_heads
+            kvd = cache["k"].shape[2] // (hd if merged else 1)
+            dup = kvd // cfg.n_kv_heads
             if dup > 1:
                 k = jnp.repeat(k, dup, axis=2)
                 v = jnp.repeat(v, dup, axis=2)
             k = k.astype(cache["k"].dtype)
             v = v.astype(cache["v"].dtype)
+            if merged:
+                k = k.reshape(B, S, kvd * hd)
+                v = v.reshape(B, S, kvd * hd)
             if S == 1:
                 new_cache = {"k": k, "v": v}
             else:
@@ -197,6 +249,9 @@ def attention_block(
                     window=window, attn_softcap=cfg.attn_softcap)
             else:
                 S_max = ck.shape[1]
+                if merged:  # prefill may view the written leaf per head
+                    ck = ck.reshape(B, S_max, kvd, hd)
+                    cv = cv.reshape(B, S_max, kvd, hd)
                 pos_k = jnp.broadcast_to(
                     jnp.arange(S_max, dtype=jnp.int32), (B, S_max))
                 kv_len = jnp.full((B,), cache_len + S, jnp.int32)

@@ -18,7 +18,7 @@ from jax import lax
 
 from repro.models import params as PD
 from repro.models import rwkv as rwkv_lib
-from repro.models.attention import attention_block
+from repro.models.attention import attention_block, merged_heads
 from repro.models.common import rmsnorm, softcap, swiglu
 from repro.models.config import ModelConfig
 from repro.models.mamba import init_mamba_cache, mamba_block
@@ -209,13 +209,11 @@ class Model:
         caches = []
         for spec in cfg.pattern():
             if spec.kind == "attn":
-                kvd = cfg.n_kv_heads * kv_dup
-                c = {
-                    "k": jnp.zeros(
-                        (r, batch, max_len, kvd, cfg.head_dim_), dtype),
-                    "v": jnp.zeros(
-                        (r, batch, max_len, kvd, cfg.head_dim_), dtype),
-                }
+                kvd, hd = cfg.n_kv_heads * kv_dup, cfg.head_dim_
+                heads = (kvd * hd,) if merged_heads(hd) else (kvd, hd)
+                shape = (r, batch, max_len, *heads)
+                c = {"k": jnp.zeros(shape, dtype),
+                     "v": jnp.zeros(shape, dtype)}
             elif spec.kind == "mamba":
                 c = jax.tree.map(
                     lambda t: jnp.broadcast_to(t, (r, *t.shape)).copy(),
@@ -246,11 +244,13 @@ class Model:
         else:
             seq_ax, b_ax = "cache_seq", "cache_batch"
         kv_ax = "cache_kv" if kv_shardable else None
+        # a merged leaf (init_cache) shards its heads-major last axis
+        attn_ax = ("layers", b_ax, seq_ax, kv_ax) + (
+            () if merged_heads(cfg.head_dim_) else (None,))
         out = []
         for spec in cfg.pattern():
             if spec.kind == "attn":
-                ax = ("layers", b_ax, seq_ax, kv_ax, None)
-                out.append({"k": ax, "v": ax})
+                out.append({"k": attn_ax, "v": attn_ax})
             elif spec.kind == "mamba":
                 out.append({
                     "conv": ("layers", b_ax, None, "d_inner"),
@@ -318,8 +318,8 @@ class Model:
                                         (params["blocks"], cache))
             if S == 1:
                 # decode: attention layers gave only their new rows, (R,
-                # B, 1, KVd, hd); one write per leaf into the stacked
-                # cache, in place where the caller donates it
+                # B, 1, ...) in the cache's form; one write per leaf into
+                # the stacked cache, in place where the caller donates it
                 with jax.named_scope("kv_cache"):
                     new_cache = tuple(
                         jax.tree.map(

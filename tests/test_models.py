@@ -98,21 +98,34 @@ _CACHED_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(_CACHED_CASES))
-def test_cached_attention_matches_write_then_attend(case):
+# head_dim 8 takes the merged leaf (B, S_max, KV * hd), 128 keeps the
+# per-head leaf (B, S_max, KV, hd); the ids of the first keep their case
+_LEAF_FORMS = [pytest.param(case, 8, id=case)
+               for case in sorted(_CACHED_CASES)] + [
+    pytest.param(case, 128, id=f"{case}-hd128")
+    for case in sorted(_CACHED_CASES)]
+
+
+@pytest.mark.parametrize("case,hd", _LEAF_FORMS)
+def test_cached_attention_matches_write_then_attend(case, hd):
     """Decode attention over the cache read in place plus the step's own
     key and value equals writing them at ``cache_len`` and attending
-    over the written cache, the form prefill keeps."""
+    over the written cache, the form prefill keeps, for the cache leaf
+    in the form its head width takes."""
     from jax import lax
 
-    from repro.models.attention import attention_core, cached_attention
+    from repro.models.attention import (attention_core, cached_attention,
+                                        merged_heads)
 
     cache_len, dup, window, cap = _CACHED_CASES[case]
-    B, S_max, H, KV, hd = 2, 16, 8, 2, 8
+    B, S_max, H, KV = 2, 16, 8, 2
     rng = np.random.default_rng(cache_len + 10 * dup + window)
 
     def normal(*shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def leaf(t):  # (B, S, KVd, hd) in the form the cache stores
+        return t.reshape(*t.shape[:2], -1) if merged_heads(hd) else t
 
     q = normal(B, 1, H, hd)
     k = jnp.repeat(normal(B, 1, KV, hd), dup, axis=2)
@@ -120,7 +133,8 @@ def test_cached_attention_matches_write_then_attend(case):
     ck, cv = normal(B, S_max, KV * dup, hd), normal(B, S_max, KV * dup, hd)
     n = jnp.int32(cache_len)
     got = jax.jit(lambda *a: cached_attention(
-        *a, window=window, attn_softcap=cap))(q, ck, cv, k, v, n)
+        *a, window=window, attn_softcap=cap))(
+        q, leaf(ck), leaf(cv), leaf(k), leaf(v), n)
     wk = lax.dynamic_update_slice_in_dim(ck, k, cache_len, axis=1)
     wv = lax.dynamic_update_slice_in_dim(cv, v, cache_len, axis=1)
     pos_k = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32),
@@ -133,24 +147,31 @@ def test_cached_attention_matches_write_then_attend(case):
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("name,cache_len,kv_dup,unroll", [
-    ("granite_3_2b", 0, 1, False),
-    ("granite_3_2b", 6, 2, False),
-    ("gemma2_9b", 11, 1, False),
-    ("jamba_1_5_large_398b", 9, 1, False),
-    ("jamba_1_5_large_398b", 4, 1, True),
-    ("qwen2_moe_a2_7b", 15, 1, False),
-])
-def test_decode_writes_only_the_new_rows(name, cache_len, kv_dup, unroll):
-    """One decode step leaves every attention cache row but the one at
-    ``cache_len`` bit-identical, and writes there the key and value a
-    prefill of the same tokens writes; its logits match that prefill's
-    last position.  The rows the cache holds past ``cache_len`` are
-    noise, which the step must not attend to.  ``unroll`` runs the
-    layer stack unrolled, as the dry run compiles it."""
-    over = {"sliding_window": 4} if name == "gemma2_9b" else {}
-    cfg = configs.get_smoke(name, capacity_factor=16.0,
-                            unroll_stack=unroll, **over)
+@pytest.mark.parametrize("hd", [16, 128])
+def test_cache_logical_axes_name_every_attention_axis(hd):
+    """Each attention cache leaf gets one logical name per axis, with
+    its sequence axis (2, after layers and batch) named ``cache_seq...``,
+    whether the leaf is merged (head_dim 16) or per head (128)."""
+    from repro.models.attention import merged_heads
+
+    cfg = configs.get_smoke("granite_3_2b", head_dim=hd)
+    m = Model(cfg, _mesh1())
+    cache = m.abstract_cache(2, 16)
+    assert cache[0]["k"].ndim == (4 if merged_heads(hd) else 5)
+    for seq_sharded, kv_shardable in ((False, True), (True, True),
+                                      (False, False)):
+        axes = m.cache_logical_axes(seq_sharded, kv_shardable)
+        for spec, c, ax in zip(cfg.pattern(), cache, axes):
+            if spec.kind != "attn":
+                continue
+            for leaf in ("k", "v"):
+                assert len(ax[leaf]) == c[leaf].ndim, (ax[leaf], c[leaf])
+                seq = [i for i, a in enumerate(ax[leaf])
+                       if a and a.startswith("cache_seq")]
+                assert seq == [2], ax[leaf]
+
+
+def _check_decode_writes_only_the_new_rows(cfg, cache_len, kv_dup):
     mesh = _mesh1()
     m = Model(cfg, mesh)
     params = m.init_params(jax.random.PRNGKey(0))
@@ -187,6 +208,40 @@ def test_decode_writes_only_the_new_rows(name, cache_len, kv_dup, unroll):
     np.testing.assert_allclose(np.asarray(logits),
                                np.asarray(ref_logits[:, -1:]),
                                atol=2e-3, rtol=1e-2)
+
+
+@pytest.mark.parametrize("name,cache_len,kv_dup,unroll", [
+    ("granite_3_2b", 0, 1, False),
+    ("granite_3_2b", 6, 2, False),
+    ("gemma2_9b", 11, 1, False),
+    ("jamba_1_5_large_398b", 9, 1, False),
+    ("jamba_1_5_large_398b", 4, 1, True),
+    ("qwen2_moe_a2_7b", 15, 1, False),
+])
+def test_decode_writes_only_the_new_rows(name, cache_len, kv_dup, unroll):
+    """One decode step leaves every attention cache row but the one at
+    ``cache_len`` bit-identical, and writes there the key and value a
+    prefill of the same tokens writes; its logits match that prefill's
+    last position.  The rows the cache holds past ``cache_len`` are
+    noise, which the step must not attend to.  ``unroll`` runs the
+    layer stack unrolled, as the dry run compiles it.  The smoke sizes'
+    heads (12-16 wide) take the merged cache leaf."""
+    over = {"sliding_window": 4} if name == "gemma2_9b" else {}
+    cfg = configs.get_smoke(name, capacity_factor=16.0,
+                            unroll_stack=unroll, **over)
+    _check_decode_writes_only_the_new_rows(cfg, cache_len, kv_dup)
+
+
+@pytest.mark.parametrize("name,hd,cache_len,kv_dup,over", [
+    ("granite_3_2b", 128, 6, 2, {}),
+    ("gemma2_9b", 256, 11, 1, {"sliding_window": 4}),
+])
+def test_decode_writes_only_the_new_rows_per_head_leaf(name, hd, cache_len,
+                                                       kv_dup, over):
+    """The same with heads a multiple of 128 lanes wide, whose cache
+    leaf keeps one axis per head (``merged_heads`` is false)."""
+    cfg = configs.get_smoke(name, capacity_factor=16.0, head_dim=hd, **over)
+    _check_decode_writes_only_the_new_rows(cfg, cache_len, kv_dup)
 
 
 @pytest.mark.parametrize("name", ["llama3_8b", "qwen2_moe_a2_7b",

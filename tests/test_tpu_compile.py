@@ -184,11 +184,29 @@ def test_decode_step_moves_no_whole_kv_cache(topo, arch, n_chips):
     only the new rows into the donated buffer: no copy and no fresh
     buffer of the whole cache (the layer scan's stacked outputs), and
     both cache leaves alias their outputs.  B=32 over 1536 positions,
-    the decode cells' shapes."""
-    from repro import configs
+    the decode cells' shapes.
 
-    hlo, dims, n_params = _decode_step_hlo(topo, configs.get(arch),
-                                           n_chips)
+    The cache is laid out row-major, its last axis on the lanes: for
+    granite's 64-wide heads the merged (..., 1536, 8 * 64) leaf, which
+    keeps the sequence axis off the lanes so that a written position is
+    whole rows; for qwen's 128-wide heads (..., 1536, 4, 128).  No
+    layer's slice of the cache is copied, as a per-head view of the
+    merged leaf would make the compiler do."""
+    from repro import configs
+    from repro.models.attention import merged_heads
+
+    cfg = configs.get(arch)
+    hlo, dims, n_params = _decode_step_hlo(topo, cfg, n_chips)
+    ndim = len(dims.split(","))
+    assert ndim == (4 if merged_heads(cfg.head_dim_) else 5), dims
+    layouts = re.findall(rf"= bf16\[{dims}\]\{{([0-9,]*):[^}}]*\}} "
+                         r"parameter\(", hlo)
+    row_major = ",".join(map(str, reversed(range(ndim))))
+    assert layouts and set(layouts) == {row_major}, layouts
+    layer = dims.split(",", 1)[1]
+    slices = re.findall(rf"= bf16\[(?:1,)?{layer}\]\{{[^}}]*\}} copy\(",
+                        hlo)
+    assert not slices, slices
     whole = rf"= bf16\[{dims}\]\{{[^}}]*\}} "
     copies = re.findall(whole + r"copy\(", hlo)
     buffers = [ln for ln in re.findall(whole + r"custom-call\(.*", hlo)
